@@ -251,6 +251,8 @@ def _run_host(o: dict, smoke: bool, workdir: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # a host mesh: the child never reaches for a chip this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, script, cfg_path], cwd=repo,
                           env=env, capture_output=True, text=True,
                           timeout=900)

@@ -30,7 +30,7 @@ from typing import Any, Dict, List, Optional, Sequence
 from repro import obs
 
 __all__ = ["main", "build_parser", "session_config_from_args",
-           "run_obs_scenario"]
+           "model_config", "run_obs_scenario"]
 
 
 # ---------------------------------------------------------------------------
@@ -257,52 +257,67 @@ def cmd_plan(args: argparse.Namespace) -> int:
 # train
 # ---------------------------------------------------------------------------
 
+def model_config(args: argparse.Namespace):
+    """The train/serve model: the arch's published widths, or its reduced
+    same-family form under ``--smoke``."""
+    from repro.configs import get_config
+
+    arch = get_config(args.arch)
+    return arch.smoke() if args.smoke else arch
+
+
 def cmd_train(args: argparse.Namespace) -> int:
     import dataclasses as _dc
+    import statistics
 
     import jax
 
-    from repro.configs import get_config
-    from repro.data import SyntheticLM, host_batch
-    from repro.launch.mesh import mesh_context
     from repro.launch.specs import configure_sp
-    from repro.launch.train import build_mesh
+    from repro.launch.train import build_mesh, train_on_mesh
     from repro.models import get_model
-    from repro.optim import AdamWConfig, cosine_schedule
-    from repro.train import Trainer, TrainerConfig, init_state, make_train_step
 
     cfg = session_config_from_args(args, workload="train")
     if _maybe_dump(args, cfg):
         return 0
 
-    arch = get_config(args.arch)
+    arch = model_config(args)
     if args.smoke:
-        arch = _dc.replace(arch.smoke(), vocab_size=2048)
-    model = get_model(arch)
-    mesh, plan = build_mesh(args, len(jax.devices()),
-                            moe=bool(arch.n_experts), session_config=cfg)
+        arch = _dc.replace(arch, vocab_size=2048)
+    shapes = jax.eval_shape(get_model(arch).init, jax.random.PRNGKey(0))
+    grad_bytes = float(sum(s.size * s.dtype.itemsize
+                           for s in jax.tree.leaves(shapes)))
+    mesh, plan, reducer = build_mesh(
+        args, len(jax.devices()), moe=bool(arch.n_experts),
+        session_config=cfg, grad_bytes=grad_bytes)
     configure_sp(arch, mesh, plan=plan)   # SP/EP contexts + planned a2a ring
 
-    state = init_state(model, jax.random.PRNGKey(0))
-    opt = AdamWConfig(schedule=cosine_schedule(args.lr, 10, args.steps))
-    step_fn = jax.jit(make_train_step(model, opt))
-    ds = SyntheticLM(arch.vocab_size, args.seq, args.batch, seed=0)
-
-    def batches():
-        i = 0
-        while True:
-            yield host_batch(ds, i)
-            i += 1
-
-    with mesh_context(mesh):
-        trainer = Trainer(
-            step_fn=step_fn, state=state, batches=batches(),
-            cfg=TrainerConfig(total_steps=args.steps, ckpt_every=50,
-                              ckpt_dir=args.ckpt_dir, log_every=20))
-        report = trainer.run()
+    report, state, batch_sharding = train_on_mesh(
+        arch, mesh, steps=args.steps, batch=args.batch, seq=args.seq,
+        lr=args.lr, reducer=reducer, ckpt_dir=args.ckpt_dir,
+        log_every=args.log_every)
     h = report["history"]
-    print(f"[train] arch={arch.name} steps={report['final_step']} "
+    for row in h:
+        print(f"[train] step {row['step']} loss {row['loss']:.4f} "
+              f"({row['sec'] * 1e3:.1f} ms)")
+    print(f"[train] arch={arch.name} d_model={arch.d_model} "
+          f"steps={report['final_step']} "
           f"loss {h[0]['loss']:.3f} -> {h[-1]['loss']:.3f}")
+    warm = [row["sec"] for row in h if row["step"] > 1]
+    if warm:
+        print(f"[train] step time after warm-up: "
+              f"{statistics.median(warm) * 1e3:.1f} ms (median of "
+              f"{len(warm)} logged steps after the first)")
+    param = jax.tree.leaves(state.params)[0]
+    batch_devs = jax.tree.leaves(batch_sharding)[0].device_set
+    print(f"[train] mesh {dict(mesh.shape)}"
+          f"{' overlap=' + reducer.mode if reducer is not None else ''}: "
+          f"params on {len(param.sharding.device_set)} device(s), "
+          f"batch on {len(batch_devs)}")
+    stats = jax.devices()[0].memory_stats()
+    if stats and "peak_bytes_in_use" in stats:
+        print(f"[train] peak device memory "
+              f"{stats['peak_bytes_in_use'] / 2**30:.2f} GiB "
+              f"({jax.devices()[0].device_kind})")
     return 0
 
 
@@ -314,8 +329,6 @@ def cmd_serve(args: argparse.Namespace) -> int:
     import jax
     import jax.numpy as jnp
 
-    from repro.configs import get_config
-    from repro.launch.mesh import mesh_context
     from repro.launch.specs import configure_sp
     from repro.launch.train import build_mesh
     from repro.models import get_model
@@ -326,21 +339,17 @@ def cmd_serve(args: argparse.Namespace) -> int:
     # decode payloads are smaller than gradient payloads: keep the old
     # serve launcher's 1e6 default unless the payload was set explicitly
     # (flag, config file, or environment)
-    import os
-
     if args.payload_bytes is None and args.config is None \
             and "REPRO_PAYLOAD_BYTES" not in os.environ:
         cfg = cfg.replace(payload_bytes=1e6)
     if _maybe_dump(args, cfg):
         return 0
 
-    arch = get_config(args.arch)
-    if args.smoke:
-        arch = arch.smoke()
+    arch = model_config(args)
     model = get_model(arch)
     mix = serve_mix(cfg.payload_bytes, moe=bool(arch.n_experts))
-    mesh, plan = build_mesh(args, len(jax.devices()), mix=mix,
-                            session_config=cfg)
+    mesh, plan, _ = build_mesh(args, len(jax.devices()), mix=mix,
+                               session_config=cfg)
     configure_sp(arch, mesh, plan=plan)
 
     params = model.init(jax.random.PRNGKey(0))
@@ -356,7 +365,7 @@ def cmd_serve(args: argparse.Namespace) -> int:
         [(11 * i + j) % arch.vocab_size for j in range(args.prompt_len)]
         for i in range(args.batch)
     ]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         eng = GenerationEngine(
             model, params,
             GenerationConfig(max_new_tokens=args.max_new, eos_token=-1),
@@ -369,8 +378,17 @@ def cmd_serve(args: argparse.Namespace) -> int:
             outs = eng.generate(prompts, frontend_embeds=fe)
         dt = max(timer.elapsed, 1e-9)
     total = sum(len(o) for o in outs)
-    print(f"[serve] arch={arch.name} {total} tokens in {dt:.2f}s "
-          f"({total / dt:.1f} tok/s)")
+    print(f"[serve] arch={arch.name} d_model={arch.d_model} "
+          f"{total} tokens in {dt:.2f}s ({total / dt:.1f} tok/s); "
+          f"row 0: {outs[0][:8]}")
+    # no eos is set, so every row must carry max_new in-vocabulary ids
+    bad = [t for o in outs for t in o if not 0 <= t < arch.vocab_size]
+    if bad or any(len(o) != args.max_new for o in outs):
+        print(f"[serve] FAIL: want {args.batch}x{args.max_new} ids in "
+              f"[0, {arch.vocab_size}), got lengths "
+              f"{[len(o) for o in outs]} and {len(bad)} out-of-range ids",
+              file=sys.stderr)
+        return 1
     return 0
 
 
@@ -995,10 +1013,15 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--seq", type=int, default=64)
     p.add_argument("--reorder", choices=["none", "simulate", "probe"],
                    default="simulate")
-    p.add_argument("--smoke", action="store_true", default=True,
-                   help="reduced config (CPU); drop on a real fleet")
-    p.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
+    p.add_argument("--smoke", action="store_true",
+                   help="reduced same-family config (d_model 64) for CPU "
+                        "runs; default: the arch's published widths")
+    p.add_argument("--ckpt-dir", default=None,
+                   help="write checkpoints here (default: none)")
     p.add_argument("--lr", type=float, default=1e-3)
+    p.add_argument("--log-every", type=int, default=20,
+                   help="log loss and step time every N steps (and the "
+                        "first two)")
     p.set_defaults(fn=cmd_train, mesh_default="1x1")
 
     p = sub.add_parser("serve", help="batched generation on a planned mesh")
@@ -1009,7 +1032,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max-new", type=int, default=32)
     p.add_argument("--reorder", choices=["none", "simulate", "probe"],
                    default="simulate")
-    p.add_argument("--smoke", action="store_true", default=True)
+    p.add_argument("--smoke", action="store_true",
+                   help="reduced same-family config (d_model 64) for CPU "
+                        "runs; default: the arch's published widths")
     p.set_defaults(fn=cmd_serve, mesh_default="1x1")
 
     p = sub.add_parser("bench", help="session/plan pipeline benchmark")
@@ -1086,10 +1111,20 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    if args.cmd not in ("train", "serve"):
+        return args.fn(args)
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.train import LaunchError
+
+    enable_compile_cache()
     # train/serve build meshes: give --mesh a launcher default of 1x1
-    if getattr(args, "mesh", None) is None and hasattr(args, "mesh_default"):
+    if args.mesh is None:
         args.mesh = args.mesh_default
-    return args.fn(args)
+    try:
+        return args.fn(args)
+    except LaunchError as e:
+        print(f"repro {args.cmd}: error: {e}", file=sys.stderr)
+        return 2
 
 
 if __name__ == "__main__":

@@ -8,20 +8,14 @@ same call sites work in tests (CPU, interpret=True) and production
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
 from .flash_attention import flash_attention
-from .ring_collective import fused_add, ring_all_reduce, ring_reduce_scatter
+from .ring_collective import (fused_add, on_tpu, ring_all_reduce,
+                              ring_reduce_scatter)
 from .rwkv6_chunked import wkv_chunked_matmul
 from .rwkv6_scan import wkv_scan
 
 __all__ = ["attention_op", "wkv_op", "wkv_chunked_op", "fused_add",
            "ring_reduce_scatter", "ring_all_reduce", "on_tpu"]
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def wkv_chunked_op(r, k, v, w, u, chunk=16):

@@ -46,8 +46,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.collective.executors import LoweredSchedule
 
-from .ring_collective import fused_add
-from .schedule_runner import _shard_map, schedule_tables
+from .ring_collective import accumulate
+from .schedule_runner import schedule_tables
 
 __all__ = [
     "OverlapSlot",
@@ -132,6 +132,48 @@ def build_overlap_plan(schedule: LoweredSchedule,
     return OverlapPlan(schedule, n_compute, slots)
 
 
+def _check_inputs(schedule: LoweredSchedule, shape: Tuple[int, ...]) -> None:
+    """Validate ``[n, D]`` rank-major inputs against the declared init."""
+    n, n_chunks = schedule.n, schedule.n_chunks
+    if len(shape) != 2 or shape[0] != n:
+        raise ValueError(f"want [n={n}, D] rank-major inputs, got {shape}")
+    if schedule.init == "replicated":
+        if shape[1] % n_chunks:
+            raise ValueError(f"D={shape[1]} not divisible by "
+                             f"n_chunks={n_chunks}")
+    elif schedule.init == "addressed":
+        if n_chunks != n * n or shape[1] % n:
+            raise ValueError(f"addressed init wants n_chunks=n^2 and "
+                             f"D divisible by n, got {shape}")
+    elif schedule.init != "sharded":
+        raise ValueError(f"unknown init {schedule.init!r}")
+
+
+def _rank_buffer(schedule: LoweredSchedule, xr, r) -> jnp.ndarray:
+    """Rank ``r``'s ``[n_chunks + 1, chunk_len]`` buffer from its input row.
+
+    ``xr`` is rank ``r``'s row of the declared init (``replicated``: the
+    full local vector; ``sharded``: its own chunk; ``addressed``: its n
+    outgoing pieces); the last row is the zero scratch row.
+    """
+    n, n_chunks = schedule.n, schedule.n_chunks
+    if schedule.init == "replicated":
+        chunk_len = xr.shape[0] // n_chunks
+        # the barrier keeps the flat row as it is: fused with the reshape
+        # of a [vocab, d_model] gradient that produced it, the split into
+        # chunk rows becomes a relayout that takes the TPU compiler minutes
+        body = jax.lax.optimization_barrier(xr).reshape(n_chunks, chunk_len)
+    elif schedule.init == "sharded":
+        chunk_len = xr.shape[0]
+        body = jnp.zeros((n_chunks, chunk_len), xr.dtype).at[r].set(xr)
+    else:                                                    # addressed
+        chunk_len = xr.shape[0] // n
+        body = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_chunks, chunk_len), xr.dtype),
+            xr.reshape(n, chunk_len), (r * n, 0))
+    return jnp.concatenate([body, jnp.zeros((1, chunk_len), xr.dtype)])
+
+
 def seed_state(schedule: LoweredSchedule, x) -> jnp.ndarray:
     """Position-major ``[n, n_chunks + 1, chunk_len]`` state from inputs.
 
@@ -140,36 +182,11 @@ def seed_state(schedule: LoweredSchedule, x) -> jnp.ndarray:
     and row ``n_chunks`` is the zero scratch row that absorbs
     non-participating positions.
     """
-    n, n_chunks = schedule.n, schedule.n_chunks
     x = jnp.asarray(x)
-    if x.ndim != 2 or x.shape[0] != n:
-        raise ValueError(f"want [n={n}, D] rank-major inputs, got {x.shape}")
-    if schedule.init == "replicated":
-        if x.shape[1] % n_chunks:
-            raise ValueError(f"D={x.shape[1]} not divisible by "
-                             f"n_chunks={n_chunks}")
-        chunk_len = x.shape[1] // n_chunks
-        body = x.reshape(n, n_chunks, chunk_len)
-    elif schedule.init == "sharded":
-        chunk_len = x.shape[1]
-        body = jnp.zeros((n, n_chunks, chunk_len), x.dtype)
-        for r in range(n):
-            body = body.at[r, r].set(x[r])
-    elif schedule.init == "addressed":
-        if n_chunks != n * n or x.shape[1] % n:
-            raise ValueError(f"addressed init wants n_chunks=n^2 and "
-                             f"D divisible by n, got {x.shape}")
-        chunk_len = x.shape[1] // n
-        body = jnp.zeros((n, n_chunks, chunk_len), x.dtype)
-        for s in range(n):
-            body = body.at[s, s * n:(s + 1) * n].set(
-                x[s].reshape(n, chunk_len))
-    else:
-        raise ValueError(f"unknown init {schedule.init!r}")
-    buf = jnp.concatenate(
-        [body, jnp.zeros((n, 1, chunk_len), x.dtype)], axis=1)
-    rank_of = np.asarray(schedule.rank_of, dtype=np.int64)
-    return buf[rank_of]
+    _check_inputs(schedule, x.shape)
+    bufs = jax.vmap(lambda xr, r: _rank_buffer(schedule, xr, r))(
+        x, jnp.arange(schedule.n))
+    return bufs[np.asarray(schedule.rank_of, dtype=np.int64)]
 
 
 def finish_state(schedule: LoweredSchedule, state) -> jnp.ndarray:
@@ -178,7 +195,39 @@ def finish_state(schedule: LoweredSchedule, state) -> jnp.ndarray:
     return jnp.asarray(state)[order][:, :schedule.n_chunks]
 
 
-def _make_issue(mesh: Mesh, axis: str, rnd_tables, cols: np.ndarray):
+def _seed_on_mesh(mesh: Mesh, axis: str, schedule: LoweredSchedule,
+                  x) -> jnp.ndarray:
+    """:func:`seed_state` for ``x`` sharded over ``mesh[axis]``.
+
+    Each device builds its own rank's buffer, and one ``ppermute`` moves
+    rank ``rank_of[p]``'s buffer to position p: the state never leaves
+    its shards (a global gather over the sharded axis is what the TPU
+    compiler handles slowly at gradient sizes).
+    """
+    _check_inputs(schedule, tuple(np.shape(x)))
+    links = [(int(r), p) for p, r in enumerate(schedule.rank_of)]
+
+    def per_device(rows):
+        buf = _rank_buffer(schedule, rows[0], jax.lax.axis_index(axis))
+        return jax.lax.ppermute(buf, axis, links)[None]
+
+    return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P(axis), check_vma=False)(x)
+
+
+def _finish_on_mesh(mesh: Mesh, axis: str, schedule: LoweredSchedule,
+                    state) -> jnp.ndarray:
+    """:func:`finish_state` for a state sharded over ``mesh[axis]``."""
+    links = [(p, int(r)) for p, r in enumerate(schedule.rank_of)]
+
+    def per_device(rows):
+        return jax.lax.ppermute(rows[0], axis, links)[None, :schedule.n_chunks]
+
+    return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P(axis), check_vma=False)(state)
+
+
+def _make_issue(mesh: Mesh, axis: str, rnd_tables, cols: slice):
     """shard_map'd transfer of one (round, piece): gather + ppermute.
 
     Returns ``None`` when the round has no effective links.  Output is
@@ -193,21 +242,20 @@ def _make_issue(mesh: Mesh, axis: str, rnd_tables, cols: np.ndarray):
     def per_device(rows):
         buf = rows[0]
         me = jax.lax.axis_index(axis)
-        c = jnp.asarray(cols)
         outs = []
         for eff_links, send in live:
             my_send = jnp.asarray(send)[me]               # [m]
-            payload = buf[my_send[:, None], c[None, :]]
+            payload = buf[my_send, cols]
             outs.append(jax.lax.ppermute(payload, axis, eff_links)[None])
         return tuple(outs)
 
-    return _shard_map(per_device, mesh, (P(axis),),
-                      tuple(P(axis) for _ in live))
+    return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=tuple(P(axis) for _ in live),
+                         check_vma=False)
 
 
 def _make_apply(mesh: Mesh, axis: str, rnd_tables, rnd_ops,
-                cols: np.ndarray, n_chunks: int,
-                use_pallas_add: bool, interpret: bool):
+                cols: slice, n_chunks: int, use_pallas_add: bool):
     """shard_map'd round barrier: land staged receives, re-zero scratch."""
     live = [((eff, recv), op)
             for (eff, _, recv), op in zip(rnd_tables, rnd_ops) if eff]
@@ -217,27 +265,23 @@ def _make_apply(mesh: Mesh, axis: str, rnd_tables, rnd_ops,
     def per_device(rows, *staged):
         buf = rows[0]
         me = jax.lax.axis_index(axis)
-        c = jnp.asarray(cols)
         for ((eff_links, recv), op), rx in zip(live, staged):
             received = rx[0]                              # [m, piece_len]
             my_recv = jnp.asarray(recv)[me]               # [m]
-            rows_idx = my_recv[:, None]
             if op == "reduce":
-                tgt = buf[rows_idx, c[None, :]]
-                if use_pallas_add:
-                    new = fused_add(tgt, received, interpret=interpret)
-                else:
-                    new = tgt + received
+                new = accumulate(buf[my_recv, cols], received,
+                                 use_pallas_add)
             else:
                 new = received
-            buf = buf.at[rows_idx, c[None, :]].set(new)
+            buf = buf.at[my_recv, cols].set(new)
             # non-receiving positions landed in the scratch row; re-zero
             # it so every later gather still reads zeros
             buf = buf.at[n_chunks].set(jnp.zeros_like(buf[n_chunks]))
         return buf[None]
 
     in_specs = (P(axis),) + tuple(P(axis) for _ in live)
-    return _shard_map(per_device, mesh, in_specs, P(axis))
+    return jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(axis), check_vma=False)
 
 
 def run_overlapped(
@@ -248,7 +292,6 @@ def run_overlapped(
     compute: Sequence[Callable[[], Any]] = (),
     *,
     use_pallas_add: bool = True,
-    interpret: bool = True,
     state: Optional[jnp.ndarray] = None,
     rounds: Optional[Tuple[int, Optional[int]]] = None,
     return_state: bool = False,
@@ -284,7 +327,7 @@ def run_overlapped(
         raise ValueError(f"mesh axis {axis!r} has {mesh.shape[axis]} "
                          f"devices, schedule wants {n}")
     if state is None:
-        state = seed_state(schedule, x)
+        state = _seed_on_mesh(mesh, axis, schedule, x)
     state = jnp.asarray(state)
     chunk_len = state.shape[-1]
     k = max(1, schedule.chunk_factor)
@@ -294,7 +337,9 @@ def run_overlapped(
     piece_len = chunk_len // k
 
     tables, ops = schedule_tables(schedule)
-    piece_cols = [np.arange(piece_len) + p * piece_len for p in range(k)]
+    # a piece is a static column window of every chunk row
+    piece_cols = [slice(p * piece_len, (p + 1) * piece_len)
+                  for p in range(k)]
 
     def stage_fns(slot):
         if slot.round_index < 0:
@@ -303,7 +348,7 @@ def run_overlapped(
         issue = _make_issue(mesh, axis, tables[slot.round_index], cols)
         apply_ = _make_apply(mesh, axis, tables[slot.round_index],
                              ops[slot.round_index], cols, n_chunks,
-                             use_pallas_add, interpret)
+                             use_pallas_add)
         return issue, apply_
 
     results: List[Any] = [None] * len(compute)
@@ -334,4 +379,4 @@ def run_overlapped(
 
     if return_state:
         return state, results
-    return finish_state(schedule, state), results
+    return _finish_on_mesh(mesh, axis, schedule, state), results

@@ -1,21 +1,12 @@
 """Rank-reordered ring reduce-scatter / all-reduce — the paper's object.
 
-Two implementations:
-
-* :func:`ring_reduce_scatter` — ``shard_map`` ring: N-1 steps of
-  ``ppermute`` (neighbor order = **the solved rank permutation**) with the
-  local accumulation fused by a small Pallas add kernel
-  (:func:`_fused_add`).  This is the portable path: it runs (and is
-  tested) on CPU in interpret mode and on TPU as-is.  The ``perm``
-  argument is where Cloud-Collectives plugs in: the neighbor list is the
-  ring order produced by :mod:`repro.core.solver`.
-
-* :func:`remote_ring_reduce_scatter_tpu` — all-Pallas RDMA version using
-  ``pltpu.make_async_remote_copy`` between neighbor devices, following the
-  JAX distributed-Pallas recipe (double-buffered, semaphore-synchronized).
-  TPU-only: Mosaic remote DMAs do not exist on the CPU backend, so this
-  path is exercised only on real hardware; its semantics oracle is
-  :func:`repro.kernels.ref.ring_reduce_scatter_ref` like the portable one.
+:func:`ring_reduce_scatter` is a ``shard_map`` ring: N-1 steps of
+``ppermute`` (neighbor order = **the solved rank permutation**) with the
+local accumulation fused by a small Pallas add kernel (:func:`fused_add`,
+through :func:`accumulate`).  The kernel compiles on a TPU and runs in
+interpret mode on every other backend.  The ``perm`` argument is where
+Cloud-Collectives plugs in: the neighbor list is the ring order produced
+by :mod:`repro.core.solver`.
 
 Note the equivalence: XLA's own reduce-scatter follows mesh-axis order,
 so on the *reordered mesh* the plain ``jax.lax.psum_scatter`` already
@@ -32,11 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["fused_add", "ring_reduce_scatter", "ring_all_reduce",
-           "remote_ring_reduce_scatter_tpu"]
+__all__ = ["on_tpu", "fused_add", "accumulate", "ring_reduce_scatter",
+           "ring_all_reduce"]
 
 
 # ---------------------------------------------------------------------------
@@ -71,6 +61,24 @@ def fused_add(a: jnp.ndarray, b: jnp.ndarray, block: int = 1024,
     return out[:n].reshape(a.shape)
 
 
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def accumulate(a: jnp.ndarray, b: jnp.ndarray,
+               use_pallas_add: bool) -> jnp.ndarray:
+    """The collectives' reduce step: ``a + b``.
+
+    With ``use_pallas_add`` the sum goes through :func:`fused_add`,
+    compiled on a TPU and interpreted on every other backend: the one
+    place the certified collective paths decide interpret mode.
+    """
+    if use_pallas_add:
+        return fused_add(a, b, interpret=not on_tpu())
+    return a + b
+
+
 # ---------------------------------------------------------------------------
 # portable ring (shard_map + ppermute), neighbor order = solved perm
 # ---------------------------------------------------------------------------
@@ -95,7 +103,6 @@ def ring_reduce_scatter(
     axis: str,
     perm: Optional[Sequence[int]] = None,
     use_pallas_add: bool = True,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Reduce-scatter over ``axis`` with an explicit reordered ring.
 
@@ -133,9 +140,7 @@ def ring_reduce_scatter(
             received = jax.lax.ppermute(buf, axis, links)
             idx = perm_arr[(i - s - 2) % n]
             mine = jnp.take(chunks, idx, axis=0)
-            if use_pallas_add:
-                return fused_add(received, mine, interpret=interpret)
-            return received + mine
+            return accumulate(received, mine, use_pallas_add)
 
         buf = jax.lax.fori_loop(0, n - 1, body, buf)
         return buf[None]
@@ -160,65 +165,3 @@ def ring_all_reduce(x, mesh, axis, perm=None, **kw):
 
     return jax.shard_map(ag, mesh=mesh, in_specs=(P(axis),),
                          out_specs=P(axis), check_vma=False)(rs)
-
-
-# ---------------------------------------------------------------------------
-# TPU-only RDMA ring (make_async_remote_copy) — production fast path
-# ---------------------------------------------------------------------------
-
-def _rdma_ring_kernel(chunk_ref, out_ref, comm_buf, send_sem, recv_sem,
-                      *, n: int, links):
-    """One reduce-scatter pass: N-1 rounds of neighbor RDMA + accumulate.
-
-    Follows the jax.dev distributed-Pallas recipe: double-buffered
-    ``comm_buf`` (slot alternation), remote copy to the ring successor,
-    semaphore wait, accumulate into ``out_ref``.
-    """
-    my_id = jax.lax.axis_index("x")
-    out_ref[...] = chunk_ref[...]
-
-    def round_body(s, _):
-        slot = s % 2
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=out_ref,
-            dst_ref=comm_buf.at[1 - slot],
-            send_sem=send_sem,
-            recv_sem=recv_sem,
-            device_id=(my_id + 1) % n,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-        rdma.wait()
-        out_ref[...] = out_ref[...] + comm_buf[1 - slot]
-        return ()
-
-    jax.lax.fori_loop(0, n - 1, round_body, ())
-
-
-def remote_ring_reduce_scatter_tpu(x: jnp.ndarray, mesh: Mesh, axis: str,
-                                   perm: Optional[Sequence[int]] = None):
-    """All-Pallas RDMA ring reduce-scatter.  TPU only (Mosaic remote DMA);
-    semantics oracle: ref.ring_reduce_scatter_ref.  The reordered ring is
-    realized by constructing ``mesh`` from the solved device permutation —
-    the kernel always talks to its mesh neighbor, which *is* the paper's
-    insertion point (neighbor identity comes from the mesh order)."""
-    if jax.default_backend() != "tpu":  # pragma: no cover
-        raise NotImplementedError("remote DMA ring requires a TPU backend")
-    n = mesh.shape[axis]
-
-    def per_device(chunk):
-        return pl.pallas_call(
-            functools.partial(_rdma_ring_kernel, n=n, links=None),
-            out_shape=jax.ShapeDtypeStruct(chunk.shape[1:], chunk.dtype),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-            scratch_shapes=[
-                pltpu.VMEM((2,) + tuple(chunk.shape[1:]), chunk.dtype),
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
-            ],
-        )(chunk[0])[None]
-
-    f = jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
-                      out_specs=P(axis), check_vma=False)
-    return f(x)

@@ -46,37 +46,43 @@ def _wkv_chunk_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)         # [T, V]
     w = w_ref[0].astype(jnp.float32)         # [T, K] decays in (0, 1)
-    u = u_ref[0].astype(jnp.float32)         # [K]
+    u = u_ref[0].astype(jnp.float32)         # [1, K]
     S0 = state_scr[...]                      # [K, V]
 
+    T = r.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+
     log_w = jnp.log(w)
-    la = jnp.cumsum(log_w, axis=0)           # log A_t
+    # log A_t = sum_{s<=t} log w_s, as a lower-triangular matmul: the TPU
+    # kernel compiler has no cumsum
+    la = jax.lax.dot_general(
+        jnp.where(row >= col, 1.0, 0.0), log_w, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                  # [T, K]
     A = jnp.exp(la)                          # [T, K]
     A_prev = jnp.exp(la - log_w)             # A_{t-1} (A_0 = 1)
     r_t = r * A_prev                         # r~
     k_t = k * jnp.exp(-la)                   # k~
 
-    T = r.shape[0]
     inter = jax.lax.dot_general(
         r_t, S0, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                  # [T, V]
     qk = jax.lax.dot_general(
         r_t, k_t, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                  # [T, T]
-    row = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
     qk = jnp.where(row > col, qk, 0.0)                       # strict lower
     intra = jax.lax.dot_general(
         qk, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                  # [T, V]
-    bonus = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True) * v
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True) * v
     o_ref[0] = (inter + intra + bonus).astype(o_ref.dtype)
 
-    A_T = A[-1]                                              # [K]
+    A_T = A[T - 1:T]                                         # [1, K]
     kv = jax.lax.dot_general(
-        k_t * A_T[None, :], v, (((0,), (0,)), ((), ())),
+        k_t * A_T, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                  # [K, V]
-    state_scr[...] = A_T[:, None] * S0 + kv
+    state_scr[...] = A_T.T * S0 + kv
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -100,7 +106,9 @@ def wkv_chunked_matmul(
         return x.transpose(0, 2, 1, 3).reshape(B * H, S, x.shape[-1])
 
     rf, kf, vf, wf = fold(r), fold(k), fold(v), fold(w)
-    uf = jnp.broadcast_to(u[None], (B, H, K)).reshape(B * H, K)
+    # a unit axis keeps u's block (1, 1, K) equal to the array's last two
+    # dims, as the TPU tiling requires
+    uf = jnp.broadcast_to(u[None], (B, H, K)).reshape(B * H, 1, K)
 
     out = pl.pallas_call(
         functools.partial(_wkv_chunk_kernel, chunk=chunk),
@@ -110,7 +118,7 @@ def wkv_chunked_matmul(
             pl.BlockSpec((1, chunk, K), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, V), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, K), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, K), lambda bh, c: (bh, 0)),
+            pl.BlockSpec((1, 1, K), lambda bh, c: (bh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, V), lambda bh, c: (bh, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, V), v.dtype),

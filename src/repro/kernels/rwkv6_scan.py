@@ -27,7 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["wkv_scan"]
 
 
-def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr,
+def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr, y_scr,
                 *, chunk: int, n_chunks: int):
     ci = pl.program_id(1)
 
@@ -35,20 +35,21 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    u = u_ref[0].astype(jnp.float32)                  # [K]
-
-    def body(t, state):
-        r_t = r_ref[0, t].astype(jnp.float32)         # [K]
-        k_t = k_ref[0, t].astype(jnp.float32)
-        v_t = v_ref[0, t].astype(jnp.float32)         # [V]
-        w_t = w_ref[0, t].astype(jnp.float32)
-        kv = k_t[:, None] * v_t[None, :]              # [K, V]
-        y = jnp.sum((state + u[:, None] * kv) * r_t[:, None], axis=0)
-        o_ref[0, t] = y.astype(o_ref.dtype)
-        return w_t[:, None] * state + kv
-
-    state = jax.lax.fori_loop(0, chunk, body, state_scr[...])
+    # channel-major [K, T] copies: token t is the static column t:t+1, so
+    # every slice below has a static offset, as the TPU tiling requires
+    r = r_ref[0].astype(jnp.float32).T                # [K, T]
+    k = k_ref[0].astype(jnp.float32).T
+    w = w_ref[0].astype(jnp.float32).T
+    v = v_ref[0].astype(jnp.float32)                  # [T, V]
+    u = u_ref[0].astype(jnp.float32).T                # [K, 1]
+    state = state_scr[...]                            # [K, V]
+    for t in range(chunk):
+        kv = k[:, t:t + 1] * v[t:t + 1, :]            # [K, V]
+        y_scr[t:t + 1, :] = jnp.sum((state + u * kv) * r[:, t:t + 1],
+                                    axis=0, keepdims=True)
+        state = w[:, t:t + 1] * state + kv
     state_scr[...] = state
+    o_ref[0] = y_scr[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -73,7 +74,9 @@ def wkv_scan(
         return x.transpose(0, 2, 1, 3).reshape(B * H, S, x.shape[-1])
 
     rf, kf, vf, wf = fold(r), fold(k), fold(v), fold(w)
-    uf = jnp.broadcast_to(u[None], (B, H, K)).reshape(B * H, K)
+    # a unit axis keeps u's block (1, 1, K) equal to the array's last two
+    # dims, as the TPU tiling requires
+    uf = jnp.broadcast_to(u[None], (B, H, K)).reshape(B * H, 1, K)
 
     grid = (B * H, n_chunks)
     kernel = functools.partial(_wkv_kernel, chunk=chunk, n_chunks=n_chunks)
@@ -85,11 +88,12 @@ def wkv_scan(
             pl.BlockSpec((1, chunk, K), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, V), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, K), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, K), lambda bh, c: (bh, 0)),
+            pl.BlockSpec((1, 1, K), lambda bh, c: (bh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, V), lambda bh, c: (bh, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, V), v.dtype),
-        scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((K, V), jnp.float32),
+                        pltpu.VMEM((chunk, V), jnp.float32)],
         interpret=interpret,
     )(rf, kf, vf, wf, uf)
     return out.reshape(B, H, S, V).transpose(0, 2, 1, 3)

@@ -38,7 +38,7 @@ runner can be this trusting *because* the schedule carries a proof.
 from __future__ import annotations
 
 import functools
-from typing import List, Tuple
+from typing import Callable, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,21 +47,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.collective.executors import LoweredSchedule
 
-from .ring_collective import fused_add
+from .ring_collective import accumulate
 
-__all__ = ["run_schedule", "check_postcondition", "schedule_tables"]
-
-
-def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions: the top-level ``jax.shard_map``
-    (``check_vma``) when present, else the 0.4.x
-    ``jax.experimental.shard_map`` (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+__all__ = ["run_schedule", "schedule_body", "check_postcondition",
+           "schedule_tables"]
 
 
 def _step_tables(step, n: int, n_chunks: int):
@@ -139,13 +128,75 @@ def _initial_buffers(schedule: LoweredSchedule,
     return buf, chunk_len
 
 
+def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
+                  use_pallas_add: bool = True
+                  ) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    """The traceable body of :func:`run_schedule`, over ``mesh[axis]``.
+
+    Maps position-major ``[n, n_chunks + 1, chunk_len]`` buffers (the
+    device at axis position p holds logical rank ``rank_of[p]``'s
+    buffer, row ``n_chunks`` the zero scratch row) to the same layout
+    after the last round.  It depends only on the buffers' shape, so it
+    can be lowered from a ``ShapeDtypeStruct``.
+    """
+    n = schedule.n
+    if mesh.shape[axis] != n:
+        raise ValueError(f"mesh axis {axis!r} has {mesh.shape[axis]} "
+                         f"devices, schedule wants {n}")
+    k = schedule.chunk_factor
+    # static per-step tables, resolved once per schedule (memoised —
+    # repeated calls on the same certified artifact skip the rebuild)
+    tables, ops = schedule_tables(schedule)
+
+    def per_device(rows):
+        buf = rows[0]                              # [n_chunks+1, chunk_len]
+        chunk_len = buf.shape[-1]
+        if chunk_len % k:
+            raise ValueError(
+                f"chunk_len {chunk_len} not divisible by chunk_factor {k}")
+        piece_len = chunk_len // k
+        me = jax.lax.axis_index(axis)
+        for piece in range(k):
+            # a piece is a static column window of every chunk row
+            cols = slice(piece * piece_len, (piece + 1) * piece_len)
+            for rnd_tables, rnd_ops in zip(tables, ops):
+                entry = buf                        # round-entry snapshot
+                staged = []
+                for eff_links, send, recv in rnd_tables:
+                    if not eff_links:
+                        staged.append(None)
+                        continue
+                    my_send = jnp.asarray(send)[me]          # [m]
+                    payload = entry[my_send, cols]
+                    staged.append(
+                        jax.lax.ppermute(payload, axis, eff_links))
+                for (eff_links, send, recv), op, received in zip(
+                        rnd_tables, rnd_ops, staged):
+                    if received is None:
+                        continue
+                    my_recv = jnp.asarray(recv)[me]          # [m]
+                    if op == "reduce":
+                        new = accumulate(buf[my_recv, cols], received,
+                                         use_pallas_add)
+                    else:
+                        new = received
+                    buf = buf.at[my_recv, cols].set(new)
+                    # the scratch row absorbed non-receiving positions'
+                    # zero payloads; re-zero it so later gathers stay 0
+                    buf = buf.at[schedule.n_chunks].set(
+                        jnp.zeros_like(buf[schedule.n_chunks]))
+        return buf[None]
+
+    return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P(axis), check_vma=False)
+
+
 def run_schedule(
     x,
     mesh: Mesh,
     axis: str,
     schedule: LoweredSchedule,
     use_pallas_add: bool = True,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Run ``schedule`` over ``mesh[axis]``; returns final rank buffers.
 
@@ -155,64 +206,11 @@ def run_schedule(
     declared postcondition can be checked
     (:func:`check_postcondition`).
     """
-    n = schedule.n
-    assert mesh.shape[axis] == n, (mesh.shape, n)
-    buf0, chunk_len = _initial_buffers(schedule, x)
-    k = schedule.chunk_factor
-    if chunk_len % k:
-        raise ValueError(
-            f"chunk_len {chunk_len} not divisible by chunk_factor {k}")
-    piece_len = chunk_len // k
-
+    body = schedule_body(mesh, axis, schedule, use_pallas_add)
+    buf0, _ = _initial_buffers(schedule, x)
     # device at axis position p plays logical rank rank_of[p]
     rank_of = np.asarray(schedule.rank_of, dtype=np.int64)
-    buf_pos = buf0[rank_of]                       # position-major
-
-    # static per-step tables, resolved once per schedule (memoised —
-    # repeated calls on the same certified artifact skip the rebuild)
-    tables, ops = schedule_tables(schedule)
-    cols0 = np.arange(piece_len)
-
-    def per_device(rows):
-        buf = rows[0]                              # [n_chunks+1, chunk_len]
-        me = jax.lax.axis_index(axis)
-        for piece in range(k):
-            cols = jnp.asarray(cols0 + piece * piece_len)
-            for rnd_tables, rnd_ops in zip(tables, ops):
-                entry = buf                        # round-entry snapshot
-                staged = []
-                for eff_links, send, recv in rnd_tables:
-                    if not eff_links:
-                        staged.append(None)
-                        continue
-                    my_send = jnp.asarray(send)[me]          # [m]
-                    payload = entry[my_send[:, None], cols[None, :]]
-                    staged.append(
-                        jax.lax.ppermute(payload, axis, eff_links))
-                for (eff_links, send, recv), op, received in zip(
-                        rnd_tables, rnd_ops, staged):
-                    if received is None:
-                        continue
-                    my_recv = jnp.asarray(recv)[me]          # [m]
-                    rows_idx = my_recv[:, None]
-                    if op == "reduce":
-                        tgt = buf[rows_idx, cols[None, :]]
-                        if use_pallas_add:
-                            new = fused_add(tgt, received,
-                                            interpret=interpret)
-                        else:
-                            new = tgt + received
-                    else:
-                        new = received
-                    buf = buf.at[rows_idx, cols[None, :]].set(new)
-                    # the scratch row absorbed non-receiving positions'
-                    # zero payloads; re-zero it so later gathers stay 0
-                    buf = buf.at[schedule.n_chunks].set(
-                        jnp.zeros_like(buf[schedule.n_chunks]))
-        return buf[None]
-
-    f = _shard_map(per_device, mesh, (P(axis),), P(axis))
-    out_pos = f(jnp.asarray(buf_pos))
+    out_pos = body(jnp.asarray(buf0[rank_of]))     # position-major
     # back to rank space, scratch row dropped
     order = np.asarray(schedule.order, dtype=np.int64)
     return jnp.asarray(out_pos)[order][:, :schedule.n_chunks]

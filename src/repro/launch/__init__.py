@@ -5,6 +5,8 @@ New code should go through :class:`repro.session.Session` (or ``python
 the session drives:
 
 * :mod:`repro.launch.mesh` — production / reordered / planned meshes;
+* :mod:`repro.launch.compile_cache` — where JAX's persistent
+  compilation cache lives;
 * :mod:`repro.launch.train`, :mod:`repro.launch.serve` — launcher
   internals (their ``python -m`` entry points are deprecated shims
   delegating to :mod:`repro.cli`);
@@ -17,7 +19,8 @@ Submodules import lazily so ``import repro.launch`` never touches jax.
 
 from importlib import import_module
 
-_SUBMODULES = ("dryrun", "hlo_analysis", "mesh", "serve", "specs", "train")
+_SUBMODULES = ("compile_cache", "dryrun", "hlo_analysis", "mesh", "serve",
+               "specs", "train")
 
 __all__ = list(_SUBMODULES)
 

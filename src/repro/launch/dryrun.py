@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run driver (see DESIGN.md §5 and EXPERIMENTS.md §Dry-run).
 
 For every (architecture x input shape) cell this driver:
@@ -31,6 +27,7 @@ Usage::
 import argparse
 import dataclasses
 import json
+import os
 import traceback
 from typing import Any, Dict, Optional
 
@@ -262,6 +259,9 @@ def _finish_roofline(rec, cfg, shape, n_chips: int) -> None:
 
 
 def main() -> None:
+    # 512 placeholder host devices for the production meshes; set before
+    # JAX first initialises its backends, and only for this command
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     from repro.configs import ARCH_IDS, SHAPES
 
     ap = argparse.ArgumentParser()

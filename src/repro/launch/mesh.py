@@ -62,19 +62,6 @@ def make_planned_mesh(plan, devices: Optional[Sequence] = None):
     return make_reordered_mesh(plan.mesh_plan, devices=devices)
 
 
-def mesh_context(mesh):
-    """Context manager activating ``mesh`` across jax versions.
-
-    ``jax.set_mesh`` appeared in jax 0.5; older versions use the Mesh
-    object itself as the context manager.
-    """
-    import jax
-
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def make_mesh_for_tests(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Small mesh over however many devices the test process has."""
     import jax

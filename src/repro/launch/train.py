@@ -10,12 +10,14 @@ delegates there.)  :func:`build_mesh` is the piece the CLI and tests
 share: it drives a :class:`repro.session.Session` through
 probe → plan → apply and returns the (reordered) mesh plus the compiled
 plan.  The paper's technique enters exactly once: the device order used
-to build the Mesh.
+to build the Mesh.  :func:`train_on_mesh` then runs the sharded train
+step on that mesh.
 """
 
 from __future__ import annotations
 
 import warnings
+from typing import Optional
 
 import numpy as np
 
@@ -37,8 +39,12 @@ def default_job_mix(payload_bytes: float, moe: bool = False):
     return train_mix(payload_bytes, moe=moe)
 
 
+class LaunchError(RuntimeError):
+    """The launcher cannot build the mesh or the step it was asked for."""
+
+
 def build_mesh(args, n_devices: int, mix=None, moe: bool = False,
-               session_config=None):
+               session_config=None, grad_bytes: Optional[float] = None):
     """Mesh per --reorder policy: none | simulate | probe.
 
     ``simulate``/``probe`` run the full Session lifecycle: attach (a
@@ -47,22 +53,36 @@ def build_mesh(args, n_devices: int, mix=None, moe: bool = False,
     compiled once and cached under the fabric fingerprint), apply (the
     reordered Mesh).  ``mix`` overrides the planned collective histogram
     (serving passes its decode-shaped mix); ``session_config`` supplies
-    cache dir / budget / payload when the caller (the CLI) already
-    resolved a :class:`~repro.session.SessionConfig`.
+    cache dir / budget / payload / overlap when the caller (the CLI)
+    already resolved a :class:`~repro.session.SessionConfig`.
 
-    Returns ``(mesh, plan)`` where plan is a :class:`repro.plan.Plan`
-    (or None when reordering is off).
+    Returns ``(mesh, plan, reducer)``: plan is a :class:`repro.plan.Plan`
+    (None when reordering is off), and reducer the certified gradient
+    reducer of ``Session.overlap_step`` for ``grad_bytes`` of gradients
+    when the config's overlap mode is not ``off`` (else None).
+
+    Raises :class:`LaunchError` when ``args.mesh`` does not cover exactly
+    ``n_devices`` devices, or when the planned mesh cannot be built.
     """
     from repro.launch.mesh import make_mesh_for_tests
     from repro.session import Session, SessionConfig
 
     shape, axes = parse_mesh(args.mesh)
-    if args.reorder == "none" or int(np.prod(shape)) != n_devices:
-        return make_mesh_for_tests(shape, axes), None
+    if int(np.prod(shape)) != n_devices:
+        raise LaunchError(
+            f"--mesh {args.mesh} spans {int(np.prod(shape))} devices but "
+            f"this process has {n_devices}")
+    base = session_config or SessionConfig()
+    overlap = grad_bytes is not None and base.overlap.mode != "off"
+    if args.reorder == "none":
+        if overlap:
+            raise LaunchError(
+                f"overlap mode {base.overlap.mode!r} runs the plan's "
+                f"certified all-reduce; it needs --reorder simulate or probe")
+        return make_mesh_for_tests(shape, axes), None, None
 
     from repro.session.config import FabricConfig
 
-    base = session_config or SessionConfig()
     pods = shape[0] if len(shape) == 3 else 1
     if args.reorder == "probe":
         fabric = {"kind": "live"}
@@ -83,9 +103,17 @@ def build_mesh(args, n_devices: int, mix=None, moe: bool = False,
         payload_bytes=payload if payload is not None else base.payload_bytes,
         moe=moe or base.moe,
     )
+    reducer = None
     with Session(cfg) as session:
         plan = session.plan(mix=mix)
         applied = session.apply()
+        if applied.mesh is None:
+            raise LaunchError(
+                f"the planned {args.mesh} mesh could not be built over "
+                f"{n_devices} devices (see the session warning above)")
+        if overlap:
+            reducer = session.overlap_step(applied.mesh,
+                                           total_bytes=grad_bytes)
         hit = "cache hit" if session.service.stats["cache_hits"] else \
             f"compiled in {plan.compile_seconds:.2f}s"
     mp = plan.mesh_plan
@@ -93,14 +121,77 @@ def build_mesh(args, n_devices: int, mix=None, moe: bool = False,
           f"mesh identity {mp.baseline_cost:.5f} -> optimized {mp.cost:.5f} "
           f"({mp.baseline_cost / max(mp.cost, 1e-30):.2f}x), "
           f"{len(plan.entries)} collective entries")
-    mesh = applied.mesh
-    if mesh is None:
-        warnings.warn(
-            "planned mesh could not be built; training on an "
-            "UNREORDERED mesh (see the session warning above)",
-            RuntimeWarning, stacklevel=2)
-        mesh = make_mesh_for_tests(shape, axes)
-    return mesh, plan
+    return applied.mesh, plan, reducer
+
+
+def train_on_mesh(arch, mesh, *, steps: int, batch: int, seq: int,
+                  lr: float, reducer=None, ckpt_dir: Optional[str] = None,
+                  log_every: int = 20):
+    """Train ``arch`` from a seeded init on ``mesh`` with the sharded step.
+
+    The step is :func:`repro.train.train_step.jit_train_step`: TP specs
+    plus ZeRO-1 over the mesh, or — given a ``reducer`` — the certified
+    bucketed gradient all-reduce over ``reducer.axis`` with the state
+    replicated.  The state is initialised straight into its shardings
+    and every batch is placed on the mesh before its step.
+
+    Returns ``(report, state, batch_sharding)``: the
+    :meth:`repro.train.Trainer.run` report, the final train state and
+    the sharding each batch was placed with.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.data import SyntheticLM, host_batch
+    from repro.models import get_model
+    from repro.optim import AdamWConfig, cosine_schedule
+    from repro.train import Trainer, TrainerConfig, init_state
+    from repro.train.train_step import (
+        batch_pspecs, jit_train_step, state_pspecs)
+
+    model = get_model(arch)
+    opt = AdamWConfig(schedule=cosine_schedule(lr, 10, steps))
+    ds = SyntheticLM(arch.vocab_size, seq, batch, seed=0)
+
+    def init(rng):
+        return init_state(model, rng)
+
+    rng = jax.random.PRNGKey(0)
+    state_shapes = jax.eval_shape(init, rng)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    batch_shapes = {"tokens": tokens, "labels": tokens}
+    if reducer is None:
+        def named(tree):
+            return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
+                                is_leaf=lambda x: isinstance(x, P))
+        state_ns = named(state_pspecs(state_shapes, arch, mesh))
+        batch_ns = named(batch_pspecs(batch_shapes, mesh))
+        step_fn = jit_train_step(model, opt, arch, mesh, state_shapes,
+                                 batch_shapes)
+    else:
+        # pure data parallelism: the whole state replicated, the batch
+        # split over the reducer's axis (as jit_overlap_train_step wants)
+        state_ns = NamedSharding(mesh, P())
+        batch_ns = NamedSharding(mesh, P(reducer.axis))
+        step_fn = jit_train_step(model, opt, arch, mesh, state_shapes,
+                                 batch_shapes, overlap=reducer.mode,
+                                 reducer=reducer, axis=reducer.axis)
+    state = jax.jit(init, out_shardings=state_ns)(rng)
+
+    def batches():
+        i = 0
+        while True:
+            yield jax.device_put(host_batch(ds, i), batch_ns)
+            i += 1
+
+    trainer = Trainer(
+        step_fn=step_fn, state=state, batches=batches(),
+        cfg=TrainerConfig(total_steps=steps, ckpt_every=50,
+                          ckpt_dir=ckpt_dir, log_every=log_every))
+    with jax.set_mesh(mesh):
+        report = trainer.run()
+    return report, trainer.state, batch_ns
 
 
 def main() -> None:

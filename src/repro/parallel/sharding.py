@@ -139,6 +139,17 @@ def _param_rule(
 _STACK_KEYS = ("blocks", "groups", "enc_blocks", "dec_blocks")
 
 
+def _on_mesh(spec: P, mesh: Mesh) -> P:
+    """``spec`` without the axes ``mesh`` lacks (a 1-D ``data`` mesh has
+    no ``model`` axis to shard over)."""
+    def part(p):
+        axes = tuple(a for a in (p if isinstance(p, tuple) else (p,))
+                     if a in mesh.axis_names)
+        return axes if len(axes) > 1 else (axes[0] if axes else None)
+
+    return P(*(part(p) for p in spec))
+
+
 def param_pspecs(params_shape: Any, cfg: ModelConfig, mesh: Mesh) -> Any:
     """PartitionSpec pytree matching ``params_shape`` (a shape pytree or
     real params)."""
@@ -150,7 +161,7 @@ def param_pspecs(params_shape: Any, cfg: ModelConfig, mesh: Mesh) -> Any:
         shape = tuple(leaf.shape)
         stacked = any(k in _STACK_KEYS for k in keys)
         logical = shape[1:] if stacked else shape
-        spec = _param_rule(keys, logical, cfg, mesh)
+        spec = _on_mesh(_param_rule(keys, logical, cfg, mesh), mesh)
         if stacked:
             spec = P(None, *spec)
         if len(spec) < len(shape):

@@ -122,8 +122,7 @@ class GenerationEngine:
         prog = entry.program()
         return ex.lower(prog) if ex.can_lower(prog) else None
 
-    def arm_overlap(self, mesh, axis: str, payload_bytes: float = 1e6,
-                    interpret: bool = True):
+    def arm_overlap(self, mesh, axis: str, payload_bytes: float = 1e6):
         """Fuse the planned all-gather into decode/prefill compute.
 
         Looks up the plan's all-gather entry at ``payload_bytes``,
@@ -157,8 +156,7 @@ class GenerationEngine:
         if mesh.shape[axis] != sched.n:
             raise ValueError(f"mesh axis {axis!r} has {mesh.shape[axis]} "
                              f"devices, schedule wants {sched.n}")
-        self._overlap = {"mesh": mesh, "axis": axis, "schedule": sched,
-                         "interpret": interpret}
+        self._overlap = {"mesh": mesh, "axis": axis, "schedule": sched}
 
         def step(params, cur, cache, payload):
             from repro.kernels.overlap import run_overlapped
@@ -166,7 +164,7 @@ class GenerationEngine:
             gathered, (dec,) = run_overlapped(
                 payload, mesh, axis, sched,
                 compute=[lambda: self.model.decode_step(params, cur, cache)],
-                use_pallas_add=False, interpret=interpret)
+                use_pallas_add=False)
             logits, new_cache = dec
             return logits, new_cache, gathered
 
@@ -236,7 +234,7 @@ class GenerationEngine:
                 _, (cache,) = run_overlapped(
                     payload, ov["mesh"], ov["axis"], ov["schedule"],
                     compute=[lambda: _grow_cache(cache, P, P + max_new)],
-                    use_pallas_add=False, interpret=ov["interpret"])
+                    use_pallas_add=False)
         else:
             cache = _grow_cache(cache, P, P + max_new)
 

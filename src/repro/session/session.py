@@ -569,8 +569,7 @@ class Session:
     def overlap_step(self, mesh: Any, axis: Optional[str] = None, *,
                      total_bytes: Optional[float] = None,
                      mode: Optional[str] = None,
-                     bucket_bytes: Optional[float] = None,
-                     interpret: bool = True):
+                     bucket_bytes: Optional[float] = None):
         """A certified overlap reducer for the train step's grad all-reduce.
 
         Returns an :class:`~repro.train.overlap_grads.OverlapGradReducer`
@@ -618,7 +617,7 @@ class Session:
         return reducer_from_plan(
             self._plan, mesh, axis, total, mode=mode,
             bucket_bytes=bb if bb > 0 else None,
-            use_pallas_add=cfg.use_pallas_add, interpret=interpret)
+            use_pallas_add=cfg.use_pallas_add)
 
     # -- drift: observe / monitor -----------------------------------------
     def observe(self, cost_matrix_now: np.ndarray) -> DriftReport:

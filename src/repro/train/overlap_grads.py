@@ -41,7 +41,6 @@ from repro.collective import CollectiveOp, JaxExecutor, compile_op
 from repro.collective.executors import LoweredSchedule
 from repro.collective.passes import apply_permutation, chunk as chunk_pass
 from repro.kernels.overlap import run_overlapped
-from repro.kernels.schedule_runner import _shard_map
 from repro.optim import apply_opt
 
 from .train_step import TrainState
@@ -143,7 +142,7 @@ class OverlapGradReducer:
 
     def __init__(self, mesh: Mesh, axis: str, schedule: LoweredSchedule,
                  bucket_bytes: float = 0.0, mode: str = "bucketed",
-                 use_pallas_add: bool = False, interpret: bool = True):
+                 use_pallas_add: bool = False):
         if mode not in OVERLAP_MODES:
             raise ValueError(f"mode must be one of {OVERLAP_MODES}, "
                              f"got {mode!r}")
@@ -159,7 +158,6 @@ class OverlapGradReducer:
         self.bucket_bytes = float(bucket_bytes)
         self.mode = mode
         self.use_pallas_add = use_pallas_add
-        self.interpret = interpret
         self.n = schedule.n
 
     # -- bucketing ---------------------------------------------------------
@@ -214,7 +212,10 @@ class OverlapGradReducer:
             bkt = buckets[b]
 
             def vec():
-                return outs[b].reshape(n, -1)[0, :bkt.n_elems] / n
+                # flat until sliced into leaves (see kernels.overlap's
+                # _rank_buffer: a fused reshape chain compiles slowly)
+                return jax.lax.optimization_barrier(
+                    outs[b].reshape(n, -1)[0, :bkt.n_elems] / n)
 
             if self.mode == "fused":
                 shards = []
@@ -257,8 +258,7 @@ class OverlapGradReducer:
             out_b, res = run_overlapped(
                 payload, self.mesh, self.axis, self.schedule,
                 compute=[fn for _, fn in shards],
-                use_pallas_add=self.use_pallas_add,
-                interpret=self.interpret)
+                use_pallas_add=self.use_pallas_add)
             outs[b] = out_b
             for tag, value in zip(tags, res):
                 land(tag, value)
@@ -277,8 +277,7 @@ def reducer_from_plan(plan, mesh: Mesh, axis: str, total_bytes: float,
                       group: Optional[Sequence[int]] = None,
                       mode: str = "bucketed",
                       bucket_bytes: Optional[float] = None,
-                      use_pallas_add: bool = False,
-                      interpret: bool = True) -> OverlapGradReducer:
+                      use_pallas_add: bool = False) -> OverlapGradReducer:
     """Reducer from a compiled :class:`~repro.plan.Plan`.
 
     Two ``PlanEntry`` lookups: the octave of the *full* grad payload
@@ -305,8 +304,7 @@ def reducer_from_plan(plan, mesh: Mesh, axis: str, total_bytes: float,
                                     perm=local,
                                     chunk_factor=max(1, entry_b.chunks))
     return OverlapGradReducer(mesh, axis, sched, bucket_bytes=bb, mode=mode,
-                              use_pallas_add=use_pallas_add,
-                              interpret=interpret)
+                              use_pallas_add=use_pallas_add)
 
 
 def make_overlap_train_step(model, opt_cfg, mesh: Mesh, axis: str,
@@ -327,7 +325,8 @@ def make_overlap_train_step(model, opt_cfg, mesh: Mesh, axis: str,
         loss, g = jax.value_and_grad(model.loss)(params, b)
         return loss[None], jax.tree.map(lambda t: t[None], g)
 
-    sm = _shard_map(local, mesh, (P(), P(axis)), (P(axis), P(axis)))
+    sm = jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axis)),
+                       out_specs=(P(axis), P(axis)), check_vma=False)
 
     def step(state: TrainState, batch):
         losses, gstack = sm(state.params, batch)

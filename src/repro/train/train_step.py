@@ -104,8 +104,7 @@ def jit_train_step(model, opt_cfg, cfg: ModelConfig, mesh: Mesh,
             reducer = type(reducer)(
                 reducer.mesh, reducer.axis, reducer.schedule,
                 bucket_bytes=reducer.bucket_bytes, mode=overlap,
-                use_pallas_add=reducer.use_pallas_add,
-                interpret=reducer.interpret)
+                use_pallas_add=reducer.use_pallas_add)
         return jit_overlap_train_step(model, opt_cfg, mesh, axis, reducer,
                                       donate=donate)
     step_fn = make_train_step(model, opt_cfg)

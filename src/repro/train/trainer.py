@@ -154,7 +154,9 @@ class ClusterView:
 class TrainerConfig:
     total_steps: int = 100
     ckpt_every: int = 20
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    #: checkpoint directory; None = no checkpoints (and no restore on an
+    #: elastic restart: training resumes from the live state)
+    ckpt_dir: Optional[str] = "/tmp/repro_ckpt"
     log_every: int = 10
     rerank_threshold: float = 1.2
     max_restarts: int = 3
@@ -181,7 +183,8 @@ class Trainer:
         self.cluster = cluster
         self.failure_injector = failure_injector
         self.rebuild = rebuild
-        self.ckpt = AsyncCheckpointer(cfg.ckpt_dir)
+        self.ckpt = (AsyncCheckpointer(cfg.ckpt_dir)
+                     if cfg.ckpt_dir is not None else None)
         self.history: List[Dict[str, float]] = []
         self.restarts = 0
         self._cached_param_bytes: Optional[float] = None
@@ -222,7 +225,8 @@ class Trainer:
                     raise
                 self._elastic_restart(failure)
                 step = int(self.state.step)
-        self.ckpt.wait()
+        if self.ckpt is not None:
+            self.ckpt.wait()
         return {
             "final_step": step,
             "restarts": self.restarts,
@@ -252,7 +256,9 @@ class Trainer:
             for payload in self._bucket_bytes():
                 rec.record("all-reduce", payload)
             self._observe_step(step, dt, metrics)
-            if step % self.cfg.ckpt_every == 0 or step == self.cfg.total_steps:
+            if self.ckpt is not None and (
+                    step % self.cfg.ckpt_every == 0
+                    or step == self.cfg.total_steps):
                 self.ckpt.save(step, self.state)
         return step
 
@@ -288,9 +294,11 @@ class Trainer:
         return self._cached_bucket_bytes
 
     def _observe_step(self, step: int, dt: float, metrics: Dict) -> None:
+        loss = float(metrics["loss"])
+        if not np.isfinite(loss):
+            raise FloatingPointError(f"non-finite loss {loss} at step {step}")
         if step % self.cfg.log_every == 0 or step <= 2:
-            self.history.append(
-                {"step": step, "loss": float(metrics["loss"]), "sec": dt})
+            self.history.append({"step": step, "loss": loss, "sec": dt})
         if self.straggler is not None:
             # On a real fleet this is per-host step time collected via
             # heartbeats; simulated here by observing node 0.
@@ -323,8 +331,10 @@ class Trainer:
         if self.rebuild is not None:
             self.rebuild(self)              # caller re-jits step_fn / data
         # restore from the last durable checkpoint
-        self.ckpt.wait()
-        step = latest_step(self.cfg.ckpt_dir)
+        step = None
+        if self.ckpt is not None:
+            self.ckpt.wait()
+            step = latest_step(self.cfg.ckpt_dir)
         if step is not None:
             template = jax.tree.map(np.asarray, self.state)
             restored, _, _ = restore(self.cfg.ckpt_dir, template, step)

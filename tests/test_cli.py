@@ -150,16 +150,99 @@ def test_config_precedence_file_env_flags(tmp_path, monkeypatch):
 
 @pytest.mark.slow
 def test_train_subcommand_smoke(tmp_path, capsys):
-    assert run_cli(["train", "--steps", "2", "--batch", "2", "--seq", "16",
-                    "--ckpt-dir", str(tmp_path / "ckpt")]) == 0
+    assert run_cli(["train", "--smoke", "--steps", "2", "--batch", "2",
+                    "--seq", "16", "--ckpt-dir", str(tmp_path / "ckpt")]) == 0
     assert "[train]" in capsys.readouterr().out
 
 
 @pytest.mark.slow
 def test_serve_subcommand_smoke(capsys):
-    assert run_cli(["serve", "--max-new", "2", "--batch", "2",
+    assert run_cli(["serve", "--smoke", "--max-new", "2", "--batch", "2",
                     "--prompt-len", "4"]) == 0
-    assert "[serve]" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "[serve]" in out and "4 tokens" in out
+
+
+@pytest.mark.parametrize("cmd", ["train", "serve"])
+def test_launchers_default_to_published_widths(cmd):
+    from repro.cli import build_parser, model_config
+
+    ap = build_parser()
+    full = model_config(ap.parse_args([cmd, "--arch", "qwen2-0.5b"]))
+    assert (full.d_model, full.n_layers, full.vocab_size) == \
+        (896, 24, 151936)
+    smoke = model_config(ap.parse_args([cmd, "--arch", "qwen2-0.5b",
+                                        "--smoke"]))
+    assert smoke.d_model == 64
+
+
+@pytest.mark.parametrize("cmd", ["train", "serve"])
+def test_mismatched_mesh_exits_nonzero(cmd, capsys):
+    # this process has one device; a 2x2 mesh cannot be built on it
+    assert run_cli([cmd, "--smoke", "--mesh", "2x2"]) == 2
+    err = capsys.readouterr().err
+    assert f"repro {cmd}: error: --mesh 2x2 spans 4 devices but this " \
+           f"process has 1" in err
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    import os
+
+    import jax
+
+    from repro.launch.compile_cache import CACHE_ENV, enable_compile_cache
+
+    monkeypatch.delenv(CACHE_ENV, raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        got = enable_compile_cache()
+        assert got == jax.config.jax_compilation_cache_dir
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__)))
+        assert got == os.path.join(checkout, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_dir_stands(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the launcher sets no other
+    directory and compiled programs land in the one JAX was given."""
+    import os
+
+    prog = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "jax.jit(lambda x: jnp.sin(x) @ x)(jnp.ones((64, 64))).block_until_ready()\n")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, env=env)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == str(tmp_path)
+    assert list(tmp_path.iterdir()), "nothing was cached"
+
+
+def test_train_uses_every_device_with_and_without_overlap(tmp_path):
+    """On a 4-device host mesh `repro train --mesh 4` shards the batch and
+    places the state on all four devices, through the XLA all-reduce
+    (overlap off) and the plan's certified bucketed reducer."""
+    import os
+
+    base = dict(os.environ, JAX_PLATFORMS="cpu",
+                JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    for mode in ("off", "bucketed"):
+        r = subprocess.run(
+            [sys.executable, "-m", "repro", "train", "--smoke", "--mesh",
+             "4", "--steps", "2", "--batch", "4", "--seq", "16"],
+            capture_output=True, text=True,
+            env=dict(base, REPRO_OVERLAP_MODE=mode))
+        assert r.returncode == 0, r.stdout + r.stderr
+        overlap = "" if mode == "off" else " overlap=bucketed"
+        assert f"[train] mesh {{'data': 4}}{overlap}: params on 4 " \
+               f"device(s), batch on 4" in r.stdout, r.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -242,3 +242,34 @@ def test_trainer_resumes_from_checkpoint_not_zero(tmp_path):
     assert report["final_step"] == 12
     # step 6 encountered twice: once pre-failure, once after restore to 4
     assert seen_steps.count(6) == 2
+
+
+def test_trainer_stops_on_non_finite_loss(tmp_path):
+    """A diverged step raises instead of training on: the launcher exits
+    non-zero rather than reporting NaN losses."""
+    tr = _mini_trainer(tmp_path, total=3)
+    good = tr.step_fn
+
+    def nan_at_2(state, batch):
+        state, metrics = good(state, batch)
+        if int(state.step) == 2:
+            metrics = dict(metrics, loss=jnp.float32(np.nan))
+        return state, metrics
+
+    tr.step_fn = nan_at_2
+    with pytest.raises(FloatingPointError, match="non-finite loss nan at "
+                                                 "step 2"):
+        tr.run()
+
+
+def test_trainer_without_checkpoint_dir_writes_nothing(tmp_path,
+                                                       monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    tr = _mini_trainer(tmp_path / "unused", total=4)
+    tr = Trainer(step_fn=tr.step_fn, state=tr.state, batches=tr.batches,
+                 cfg=TrainerConfig(total_steps=4, ckpt_dir=None,
+                                   log_every=2))
+    report = tr.run()
+    assert report["final_step"] == 4
+    assert tr.ckpt is None
+    assert not list(tmp_path.iterdir())

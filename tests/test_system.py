@@ -137,7 +137,7 @@ from repro.parallel import pipeline_forward
 
 mesh = jax.make_mesh((8,), ("stage",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 64)), jnp.float32)
-out = ring_reduce_scatter(x, mesh, "stage", perm=[0,3,1,7,2,6,4,5], interpret=True)
+out = ring_reduce_scatter(x, mesh, "stage", perm=[0,3,1,7,2,6,4,5])
 np.testing.assert_allclose(np.asarray(out), np.asarray(ring_reduce_scatter_ref(x, 8)), atol=1e-4)
 
 # pipeline: 8 stages of y = tanh(x @ w); compare vs sequential
