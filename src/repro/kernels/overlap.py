@@ -46,8 +46,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.collective.executors import LoweredSchedule
 
-from .ring_collective import accumulate
-from .schedule_runner import schedule_tables
+from .schedule_runner import (PERMUTE_SCOPE, TABLE_SCOPE, land_receives,
+                              schedule_tables)
 
 __all__ = [
     "OverlapSlot",
@@ -208,8 +208,10 @@ def _seed_on_mesh(mesh: Mesh, axis: str, schedule: LoweredSchedule,
     links = [(int(r), p) for p, r in enumerate(schedule.rank_of)]
 
     def per_device(rows):
-        buf = _rank_buffer(schedule, rows[0], jax.lax.axis_index(axis))
-        return jax.lax.ppermute(buf, axis, links)[None]
+        with jax.named_scope(TABLE_SCOPE):
+            buf = _rank_buffer(schedule, rows[0], jax.lax.axis_index(axis))
+        with jax.named_scope(PERMUTE_SCOPE):
+            return jax.lax.ppermute(buf, axis, links)[None]
 
     return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
                          out_specs=P(axis), check_vma=False)(x)
@@ -221,7 +223,10 @@ def _finish_on_mesh(mesh: Mesh, axis: str, schedule: LoweredSchedule,
     links = [(p, int(r)) for p, r in enumerate(schedule.rank_of)]
 
     def per_device(rows):
-        return jax.lax.ppermute(rows[0], axis, links)[None, :schedule.n_chunks]
+        with jax.named_scope(PERMUTE_SCOPE):
+            moved = jax.lax.ppermute(rows[0], axis, links)
+        with jax.named_scope(TABLE_SCOPE):
+            return moved[None, :schedule.n_chunks]
 
     return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
                          out_specs=P(axis), check_vma=False)(state)
@@ -244,9 +249,11 @@ def _make_issue(mesh: Mesh, axis: str, rnd_tables, cols: slice):
         me = jax.lax.axis_index(axis)
         outs = []
         for eff_links, send in live:
-            my_send = jnp.asarray(send)[me]               # [m]
-            payload = buf[my_send, cols]
-            outs.append(jax.lax.ppermute(payload, axis, eff_links)[None])
+            with jax.named_scope(TABLE_SCOPE):
+                my_send = jnp.asarray(send)[me]           # [m]
+                payload = buf[my_send, cols]
+            with jax.named_scope(PERMUTE_SCOPE):
+                outs.append(jax.lax.ppermute(payload, axis, eff_links)[None])
         return tuple(outs)
 
     return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
@@ -266,17 +273,8 @@ def _make_apply(mesh: Mesh, axis: str, rnd_tables, rnd_ops,
         buf = rows[0]
         me = jax.lax.axis_index(axis)
         for ((eff_links, recv), op), rx in zip(live, staged):
-            received = rx[0]                              # [m, piece_len]
-            my_recv = jnp.asarray(recv)[me]               # [m]
-            if op == "reduce":
-                new = accumulate(buf[my_recv, cols], received,
-                                 use_pallas_add)
-            else:
-                new = received
-            buf = buf.at[my_recv, cols].set(new)
-            # non-receiving positions landed in the scratch row; re-zero
-            # it so every later gather still reads zeros
-            buf = buf.at[n_chunks].set(jnp.zeros_like(buf[n_chunks]))
+            buf = land_receives(buf, me, recv, cols, op, rx[0], n_chunks,
+                                use_pallas_add)
         return buf[None]
 
     in_specs = (P(axis),) + tuple(P(axis) for _ in live)

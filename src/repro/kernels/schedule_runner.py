@@ -50,7 +50,16 @@ from repro.collective.executors import LoweredSchedule
 from .ring_collective import accumulate
 
 __all__ = ["run_schedule", "schedule_body", "check_postcondition",
-           "schedule_tables"]
+           "schedule_tables", "land_receives", "PERMUTE_SCOPE",
+           "TABLE_SCOPE", "ADD_SCOPE"]
+
+#: device scopes of the certified paths (here and in
+#: :mod:`repro.kernels.overlap`): every ``ppermute``; the gather and
+#: scatter through the SEND/RECV tables and the scratch row's upkeep;
+#: the reduce
+PERMUTE_SCOPE = "certified.permute"
+TABLE_SCOPE = "certified.table"
+ADD_SCOPE = "certified.add"
 
 
 def _step_tables(step, n: int, n_chunks: int):
@@ -128,6 +137,25 @@ def _initial_buffers(schedule: LoweredSchedule,
     return buf, chunk_len
 
 
+def land_receives(buf, me, recv, cols: slice, op: str, received,
+                  n_chunks: int, use_pallas_add: bool) -> jnp.ndarray:
+    """Land one step's receives in this device's ``buf``: ``reduce``
+    accumulates into the RECV table's rows, ``copy`` overwrites them.
+
+    Non-receiving positions land in the scratch row, which is re-zeroed
+    so every later gather still reads zeros."""
+    with jax.named_scope(TABLE_SCOPE):
+        my_recv = jnp.asarray(recv)[me]                      # [m]
+    if op == "reduce":
+        with jax.named_scope(TABLE_SCOPE):
+            current = buf[my_recv, cols]
+        with jax.named_scope(ADD_SCOPE):
+            received = accumulate(current, received, use_pallas_add)
+    with jax.named_scope(TABLE_SCOPE):
+        buf = buf.at[my_recv, cols].set(received)
+        return buf.at[n_chunks].set(jnp.zeros_like(buf[n_chunks]))
+
+
 def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
                   use_pallas_add: bool = True
                   ) -> Callable[[jnp.ndarray], jnp.ndarray]:
@@ -166,25 +194,18 @@ def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
                     if not eff_links:
                         staged.append(None)
                         continue
-                    my_send = jnp.asarray(send)[me]          # [m]
-                    payload = entry[my_send, cols]
-                    staged.append(
-                        jax.lax.ppermute(payload, axis, eff_links))
+                    with jax.named_scope(TABLE_SCOPE):
+                        my_send = jnp.asarray(send)[me]      # [m]
+                        payload = entry[my_send, cols]
+                    with jax.named_scope(PERMUTE_SCOPE):
+                        staged.append(
+                            jax.lax.ppermute(payload, axis, eff_links))
                 for (eff_links, send, recv), op, received in zip(
                         rnd_tables, rnd_ops, staged):
                     if received is None:
                         continue
-                    my_recv = jnp.asarray(recv)[me]          # [m]
-                    if op == "reduce":
-                        new = accumulate(buf[my_recv, cols], received,
-                                         use_pallas_add)
-                    else:
-                        new = received
-                    buf = buf.at[my_recv, cols].set(new)
-                    # the scratch row absorbed non-receiving positions'
-                    # zero payloads; re-zero it so later gathers stay 0
-                    buf = buf.at[schedule.n_chunks].set(
-                        jnp.zeros_like(buf[schedule.n_chunks]))
+                    buf = land_receives(buf, me, recv, cols, op, received,
+                                        schedule.n_chunks, use_pallas_add)
         return buf[None]
 
     return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),

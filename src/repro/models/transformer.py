@@ -21,6 +21,14 @@ from . import layers as L
 
 Params = Dict[str, Any]
 
+#: device scopes of the training step's layers: a block's attention
+#: (projections, RoPE, scores, softmax, ``@ v``, output projection), its
+#: feed-forward (dense or MoE), and the output head with the cross
+#: entropy (:func:`lm_loss`)
+ATTENTION_SCOPE = "attention"
+MLP_SCOPE = "mlp"
+LOSS_SCOPE = "loss"
+
 
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
@@ -88,18 +96,20 @@ class DecoderLM:
         if cfg.sequence_parallel:
             x = L.sp_constrain(x)
         h = L.rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        if cfg.use_mla:
-            attn_out, _ = L.mla_attention(p["attn"], h, cfg, positions)
-        else:
-            attn_out, _ = L.attention(
-                p["attn"], h, cfg, causal=True, positions=positions,
-                window=cfg.attn_window)
+        with jax.named_scope(ATTENTION_SCOPE):
+            if cfg.use_mla:
+                attn_out, _ = L.mla_attention(p["attn"], h, cfg, positions)
+            else:
+                attn_out, _ = L.attention(
+                    p["attn"], h, cfg, causal=True, positions=positions,
+                    window=cfg.attn_window)
         x = x + attn_out
         h = L.rms_norm(x, p["mlp_norm"], cfg.norm_eps)
-        if "moe" in p:
-            y, aux = L.moe_layer(p["moe"], h, cfg)
-        else:
-            y, aux = L.mlp(p["mlp"], h), jnp.zeros((), jnp.float32)
+        with jax.named_scope(MLP_SCOPE):
+            if "moe" in p:
+                y, aux = L.moe_layer(p["moe"], h, cfg)
+            else:
+                y, aux = L.mlp(p["mlp"], h), jnp.zeros((), jnp.float32)
         return x + y, aux
 
     def _embed(self, params: Params, tokens,
@@ -326,32 +336,31 @@ def lm_loss(features: jnp.ndarray, head: jnp.ndarray, labels: jnp.ndarray,
     full [B, S, V] logits: sequence chunks are projected + reduced inside
     a rematerialized scan, so peak memory is [B, chunk, V] (forward AND
     backward).  Essential for the 150k-256k-vocab archs at 1M tokens."""
-    from . import layers as L
+    with jax.named_scope(LOSS_SCOPE):
+        B, S, D = features.shape
+        # pin the vocab sharding of the head so the chunk-scan's gradient
+        # accumulator stays vocab-sharded (an unsharded f32 [D, 256k] grad
+        # accumulator costs 4.2 GB/device on the 256k-vocab archs).
+        if head.ndim == 2:
+            head = L.sp_head_constrain(head)
+        if chunk <= 0 or S <= chunk or S % chunk != 0:
+            return _xent(features @ head, labels)
+        n = S // chunk
+        xc = features.reshape(B, n, chunk, D).transpose(1, 0, 2, 3)
+        lc = labels.reshape(B, n, chunk).transpose(1, 0, 2)
 
-    B, S, D = features.shape
-    # pin the vocab sharding of the head so the chunk-scan's gradient
-    # accumulator stays vocab-sharded (an unsharded f32 [D, 256k] grad
-    # accumulator costs 4.2 GB/device on the 256k-vocab archs).
-    if head.ndim == 2:
-        head = L.sp_head_constrain(head)
-    if chunk <= 0 or S <= chunk or S % chunk != 0:
-        return _xent(features @ head, labels)
-    n = S // chunk
-    xc = features.reshape(B, n, chunk, D).transpose(1, 0, 2, 3)
-    lc = labels.reshape(B, n, chunk).transpose(1, 0, 2)
+        def chunk_loss(xi, li):
+            # bf16 operands, f32 accumulation (a post-matmul astype would be
+            # hoisted into an f32 copy of the whole head)
+            logits = jnp.einsum("bsd,dv->bsv", xi, head,
+                                preferred_element_type=jnp.float32)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, li[..., None], axis=-1)[..., 0]
+            return jnp.sum(logz - gold)
 
-    def chunk_loss(xi, li):
-        # bf16 operands, f32 accumulation (a post-matmul astype would be
-        # hoisted into an f32 copy of the whole head)
-        logits = jnp.einsum("bsd,dv->bsv", xi, head,
-                            preferred_element_type=jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, li[..., None], axis=-1)[..., 0]
-        return jnp.sum(logz - gold)
+        def body(acc, inp):
+            xi, li = inp
+            return acc + jax.checkpoint(chunk_loss)(xi, li), None
 
-    def body(acc, inp):
-        xi, li = inp
-        return acc + jax.checkpoint(chunk_loss)(xi, li), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xc, lc))
-    return total / (B * S)
+        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xc, lc))
+        return total / (B * S)

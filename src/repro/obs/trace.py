@@ -20,7 +20,12 @@ pipeline into a bounded ring buffer.  Design constraints, in order:
   session keeps the most recent window instead of growing without bound;
 * **viewable** — :meth:`Tracer.to_chrome` emits the Chrome trace-event
   JSON format (``ph: "X"`` complete events + thread-name metadata),
-  loadable directly in Perfetto / ``chrome://tracing``.
+  loadable directly in Perfetto / ``chrome://tracing``;
+* **on the device clock** — a recording span also enters a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so while a
+  profiler trace is taken the span sits in the same ``.xplane.pb`` as
+  the device ops, on the profiler's clock.  ``jax`` is imported inside
+  the span, never by this module.
 
 :meth:`Tracer.timer` is the one deliberate exception to the
 disabled-no-clock rule: it *always* measures (the caller needs the
@@ -81,7 +86,8 @@ class Span:
     in the exported event's ``args``.
     """
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "elapsed", "_record")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "elapsed", "_record",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Optional[Dict[str, Any]], record: bool):
@@ -91,6 +97,7 @@ class Span:
         self.t0 = 0.0
         self.elapsed = 0.0
         self._record = record
+        self._annotation: Any = None
 
     def set(self, **attrs: Any) -> "Span":
         if self.attrs is None:
@@ -100,14 +107,19 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
-        self.t0 = self._tracer.clock()
         if self._record:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation(f"repro.{self.name}")
+            self._annotation.__enter__()
             self._tracer._depth_push()
+        self.t0 = self._tracer.clock()
         return self
 
     def __exit__(self, etype: Any, evalue: Any, tb: Any) -> bool:
         self.elapsed = self._tracer.clock() - self.t0
         if self._record:
+            self._annotation.__exit__(etype, evalue, tb)
             if etype is not None:
                 self.set(error=f"{etype.__name__}: {evalue}")
             self._tracer._finish_span(self)

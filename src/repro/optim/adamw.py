@@ -15,7 +15,10 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["AdamWConfig", "OptState", "init_opt", "apply_opt", "global_norm",
-           "cosine_schedule"]
+           "cosine_schedule", "OPTIMIZER_SCOPE"]
+
+#: device scope of one optimizer step, clipping included
+OPTIMIZER_SCOPE = "optimizer"
 
 
 class OptState(NamedTuple):
@@ -63,29 +66,32 @@ def apply_opt(
     cfg: AdamWConfig, params: Any, grads: Any, state: OptState
 ) -> Tuple[Any, OptState, dict]:
     """One AdamW step.  Returns (params', state', metrics)."""
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.clip_norm / (gnorm + 1e-9))
-    count = state.count + 1
-    lr = cfg.schedule(count) if cfg.schedule is not None else cfg.lr
-    b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
+    with jax.named_scope(OPTIMIZER_SCOPE):
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.clip_norm / (gnorm + 1e-9))
+        count = state.count + 1
+        lr = cfg.schedule(count) if cfg.schedule is not None else cfg.lr
+        b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
 
-    def upd(p, g, m, v):
-        g = g.astype(jnp.float32) * scale
-        m = cfg.b1 * m + (1 - cfg.b1) * g
-        v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
-        mh = m / b1c
-        vh = v / b2c
-        step = mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * step).astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            g = g.astype(jnp.float32) * scale
+            m = cfg.b1 * m + (1 - cfg.b1) * g
+            v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
+            mh = m / b1c
+            vh = v / b2c
+            step = (mh / (jnp.sqrt(vh) + cfg.eps)
+                    + cfg.weight_decay * p.astype(jnp.float32))
+            return (p.astype(jnp.float32) - lr * step).astype(p.dtype), m, v
 
-    flat_p, tdef = jax.tree.flatten(params)
-    flat_g = tdef.flatten_up_to(grads)
-    flat_m = tdef.flatten_up_to(state.m)
-    flat_v = tdef.flatten_up_to(state.v)
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = tdef.unflatten([o[0] for o in out])
-    new_m = tdef.unflatten([o[1] for o in out])
-    new_v = tdef.unflatten([o[2] for o in out])
-    metrics = {"grad_norm": gnorm, "lr": jnp.asarray(lr, jnp.float32)}
-    return new_p, OptState(new_m, new_v, count), metrics
+        flat_p, tdef = jax.tree.flatten(params)
+        flat_g = tdef.flatten_up_to(grads)
+        flat_m = tdef.flatten_up_to(state.m)
+        flat_v = tdef.flatten_up_to(state.v)
+        out = [upd(p, g, m, v)
+               for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = tdef.unflatten([o[0] for o in out])
+        new_m = tdef.unflatten([o[1] for o in out])
+        new_v = tdef.unflatten([o[2] for o in out])
+        metrics = {"grad_norm": gnorm, "lr": jnp.asarray(lr, jnp.float32)}
+        return new_p, OptState(new_m, new_v, count), metrics

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.analysis import require_certified
 from repro.collective import CollectiveOp, JaxExecutor, compile_op
 from repro.collective.executors import LoweredSchedule
@@ -57,6 +58,12 @@ __all__ = [
 ]
 
 OVERLAP_MODES = ("sequential", "bucketed", "fused")
+
+#: device scopes of the reducer's own math: the per-chip gradients
+#: stacked and packed into bucket vectors; a bucket's finishing math,
+#: the mean and the slicing of the reduced vector into gradient leaves
+PACK_SCOPE = "certified.pack"
+FINISH_SCOPE = "certified.finish"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,38 +172,27 @@ class OverlapGradReducer:
         return partition_tree(stacked_tree, self.bucket_bytes,
                               leading_axis=True)
 
-    def record_buckets(self, stacked_tree) -> List[GradBucket]:
-        """Report the per-bucket all-reduce payloads to ``repro.obs``.
-
-        Python-level (never inside a traced function): call once per
-        step, or once per (re)mesh if only the totals matter.
-        """
-        from repro import obs
-
-        buckets = self.buckets_for(stacked_tree)
-        rec = obs.recorder()
-        for b in buckets:
-            rec.record("all-reduce", float(b.n_bytes))
-        obs.metrics().gauge("train.overlap.buckets").set(len(buckets))
-        return buckets
-
     # -- the reduction -----------------------------------------------------
     def __call__(self, stacked_tree,
                  compute: Sequence[Callable[[], Any]] = ()
                  ) -> Tuple[Any, List[Any]]:
         leaves, tdef = jax.tree.flatten(stacked_tree)
         buckets = self.buckets_for(stacked_tree)
+        # set while tracing: the number of buckets the compiled step runs
+        obs.metrics().gauge("train.overlap.buckets").set(len(buckets))
         n = self.n
         quantum = self.schedule.n_chunks * max(1, self.schedule.chunk_factor)
 
         payloads = []
-        for bkt in buckets:
-            flat = [leaves[i].reshape(n, -1) for i in bkt.leaf_ids]
-            vec = flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=1)
-            pad = (-vec.shape[1]) % quantum
-            if pad:
-                vec = jnp.pad(vec, ((0, 0), (0, pad)))
-            payloads.append(vec)
+        with jax.named_scope(PACK_SCOPE):
+            for bkt in buckets:
+                flat = [leaves[i].reshape(n, -1) for i in bkt.leaf_ids]
+                vec = (flat[0] if len(flat) == 1
+                       else jnp.concatenate(flat, axis=1))
+                pad = (-vec.shape[1]) % quantum
+                if pad:
+                    vec = jnp.pad(vec, ((0, 0), (0, pad)))
+                payloads.append(vec)
 
         outs: List[Any] = [None] * len(buckets)
         finished: Dict[int, Any] = {}
@@ -222,17 +218,19 @@ class OverlapGradReducer:
                 off = 0
                 for i, sz in zip(bkt.leaf_ids, bkt.sizes):
                     def one(i=i, off=off, sz=sz):
-                        return vec()[off:off + sz].reshape(shapes[i])
+                        with jax.named_scope(FINISH_SCOPE):
+                            return vec()[off:off + sz].reshape(shapes[i])
                     shards.append((i, one))
                     off += sz
                 return shards
 
             def whole(bkt=bkt):
-                v, off, out = vec(), 0, []
-                for i, sz in zip(bkt.leaf_ids, bkt.sizes):
-                    out.append(v[off:off + sz].reshape(shapes[i]))
-                    off += sz
-                return out
+                with jax.named_scope(FINISH_SCOPE):
+                    v, off, out = vec(), 0, []
+                    for i, sz in zip(bkt.leaf_ids, bkt.sizes):
+                        out.append(v[off:off + sz].reshape(shapes[i]))
+                        off += sz
+                    return out
             return [(("bucket", b), whole)]
 
         def land(tag, value):
@@ -323,7 +321,8 @@ def make_overlap_train_step(model, opt_cfg, mesh: Mesh, axis: str,
 
     def local(params, b):
         loss, g = jax.value_and_grad(model.loss)(params, b)
-        return loss[None], jax.tree.map(lambda t: t[None], g)
+        with jax.named_scope(PACK_SCOPE):
+            return loss[None], jax.tree.map(lambda t: t[None], g)
 
     sm = jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axis)),
                        out_specs=(P(axis), P(axis)), check_vma=False)

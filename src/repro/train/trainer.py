@@ -241,25 +241,34 @@ class Trainer:
                 failed = self.failure_injector(step)
                 if failed:
                     raise NodeFailure(failed)
-            batch = next(self.batches)
-            timer = obs.tracer().timer("train.step", step=step + 1)
-            with timer:
-                self.state, metrics = self.step_fn(self.state, batch)
-                jax.block_until_ready(metrics["loss"])
-            dt = timer.elapsed
-            step += 1
-            obs.metrics().counter("train.steps").inc()
-            # the data-parallel gradient all-reduce is the step's one
-            # fleet-wide collective; record it at bucket granularity so
-            # the captured workload prices what the overlap path issues
-            rec = obs.recorder()
-            for payload in self._bucket_bytes():
-                rec.record("all-reduce", payload)
-            self._observe_step(step, dt, metrics)
-            if self.ckpt is not None and (
-                    step % self.cfg.ckpt_every == 0
-                    or step == self.cfg.total_steps):
-                self.ckpt.save(step, self.state)
+            # the step's host phases, each a span of its own: the batch
+            # drawn, the step dispatched, the wait for its loss, and the
+            # loss read back with the bookkeeping after it; the step time
+            # the trainer observes is the dispatch and the wait
+            tr = obs.tracer()
+            with tr.timer("train.step", step=step + 1):
+                with tr.span("train.batch"):
+                    batch = next(self.batches)
+                with tr.timer("train.dispatch") as dispatch:
+                    self.state, metrics = self.step_fn(self.state, batch)
+                with tr.timer("train.wait") as wait:
+                    jax.block_until_ready(metrics["loss"])
+                step += 1
+                with tr.span("train.observe"):
+                    obs.metrics().counter("train.steps").inc()
+                    # the data-parallel gradient all-reduce is the step's
+                    # one fleet-wide collective; record it at bucket
+                    # granularity so the captured workload prices what
+                    # the overlap path issues
+                    rec = obs.recorder()
+                    for payload in self._bucket_bytes():
+                        rec.record("all-reduce", payload)
+                    self._observe_step(
+                        step, dispatch.elapsed + wait.elapsed, metrics)
+                    if self.ckpt is not None and (
+                            step % self.cfg.ckpt_every == 0
+                            or step == self.cfg.total_steps):
+                        self.ckpt.save(step, self.state)
         return step
 
     def _param_bytes(self) -> float:
@@ -287,8 +296,6 @@ class Trainer:
                 buckets = partition_tree(params, self.cfg.bucket_bytes)
                 self._cached_bucket_bytes = [float(b.n_bytes)
                                              for b in buckets]
-                obs.metrics().gauge("train.overlap.buckets").set(
-                    len(buckets))
             else:
                 self._cached_bucket_bytes = [self._param_bytes()]
         return self._cached_bucket_bytes

@@ -92,6 +92,54 @@ def test_disabled_tracer_is_zero_alloc_and_records_nothing():
     assert NULL_SPAN.elapsed == 0.0
 
 
+def test_spans_annotate_the_profiler_only_when_recording(monkeypatch):
+    """A recording span opens one ``repro.<name>`` profiler annotation
+    around its interval; a disabled tracer's spans and timers open none
+    and allocate nothing."""
+    import tracemalloc
+
+    import jax.profiler
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    off = Tracer(enabled=False)
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        for _ in range(1000):
+            with off.span("train.wait"):
+                pass
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    grown = sum(d.size_diff for d in after.compare_to(before, "filename")
+                if d.traceback[0].filename.endswith("obs/trace.py"))
+    assert grown == 0
+    with off.timer("train.step"):
+        pass
+    assert opened == []
+
+    on = Tracer(enabled=True)
+    with on.span("train.step"):
+        with on.timer("train.wait"):
+            assert opened == [("enter", "repro.train.step"),
+                              ("enter", "repro.train.wait")]
+    assert opened[2:] == [("exit", "repro.train.wait"),
+                          ("exit", "repro.train.step")]
+    assert [r[1] for r in on.records()] == ["train.wait", "train.step"]
+
+
 def test_timer_measures_even_when_disabled():
     clk = FakeClock()
     tr = Tracer(enabled=False, clock=clk)

@@ -109,3 +109,54 @@ def test_certified_allreduce_body_compiles_on_four_chips(topo, monkeypatch):
         .compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+def test_bucketed_step_carries_certified_scopes(topo):
+    """The four-chip bucketed train step, lowered for v5e: its ops carry
+    the model's and the certified reducer's scopes in the locations the
+    compiler turns into op-name metadata, the reducer's gauge holds the
+    number of buckets it split the gradient into, and the program issues
+    (rounds x chunk_factor + 2) ``ppermute``s per bucket: each round's
+    transfer, the seeding move and the move back to rank order."""
+    import re
+
+    from repro import obs
+    from repro.models import get_model
+    from repro.optim import AdamWConfig
+    from repro.train import init_state, jit_train_step
+    from repro.train.overlap_grads import (OverlapGradReducer,
+                                           certified_allreduce,
+                                           partition_tree)
+
+    cfg = get_config("qwen2-0.5b").smoke()
+    model = get_model(cfg)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    state = jax.eval_shape(lambda k: init_state(model, k),
+                           jax.random.PRNGKey(0))
+    bucket = 1 << 16
+    sched = certified_allreduce(4, bucket, perm=[2, 0, 3, 1])
+    reducer = OverlapGradReducer(mesh, "data", sched, bucket_bytes=bucket)
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    state = jax.tree.map(lambda s: _sds(s.shape, s.dtype, rep), state)
+    tokens = _sds((8, 32), jnp.int32, rows)
+    prev = obs.set_metrics(obs.MetricsRegistry())
+    try:
+        lowered = jit_train_step(
+            model, AdamWConfig(), cfg, mesh, None, None, overlap="bucketed",
+            reducer=reducer).lower(state, {"tokens": tokens,
+                                           "labels": tokens})
+        gauges = obs.metrics().snapshot()["gauges"]
+    finally:
+        obs.set_metrics(prev)
+    buckets = len(partition_tree(state.params, bucket))
+    assert buckets > 1
+    assert gauges["train.overlap.buckets"] == buckets
+    permutes = lowered.as_text().count("stablehlo.collective_permute")
+    assert permutes == buckets * (len(sched.rounds)
+                                  * max(1, sched.chunk_factor) + 2)
+    text = lowered.as_text(debug_info=True)
+    for scope in ("certified.permute", "certified.table", "certified.add",
+                  "certified.pack", "certified.finish", "attention", "mlp",
+                  "loss", "optimizer"):
+        # jvp(loss): value_and_grad wraps a scope at the top of the loss
+        assert f"{scope}/" in text or f"({scope})" in text, scope
