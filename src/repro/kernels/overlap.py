@@ -5,15 +5,15 @@
 module fuses one into a surrounding step.  The schedule becomes a
 round-pipelined state machine:
 
-* **issue** — gather each step's payload from round-entry state and put
-  it on the wire (``jax.lax.ppermute``);
+* **issue** — read each step's payload rows from round-entry state and
+  put them on the wire (``jax.lax.ppermute``);
 * **apply** — land the staged receives at the round barrier (``reduce``
-  accumulates through the Pallas
+  accumulates, with XLA's add or the Pallas
   :func:`~repro.kernels.ring_collective.fused_add` kernel, ``copy``
   overwrites);
 * **overlap** — between issue and apply, run resident compute shards
   and the *next* transfer.  ``chunk_factor`` pieces of one round are
-  column-disjoint slices of the chunk buffers, so piece ``p + 1``'s
+  disjoint windows of the chunk rows, so piece ``p + 1``'s
   transfer is issued while piece ``p``'s reduce and the resident
   compute run — the generalized form of the hand-overlapped ring in
   :mod:`repro.kernels.ring_collective`.
@@ -32,6 +32,13 @@ round — partial execution goes through
 round sequence.  Interleaving therefore cannot change what the
 collective computes: :func:`run_overlapped` is element-for-element the
 same reduction order as :func:`~repro.kernels.schedule_runner.run_schedule`.
+
+Inside, each device holds its chunk rows in the runner's layout,
+``[n_chunks, *slab_shape(chunk_len, chunk_factor)]`` with the chunk on
+the major axis.  The public state of :func:`seed_state`,
+:func:`finish_state` and ``run_overlapped(state=..., return_state=True)``
+is ``[n, n_chunks + 1, chunk_len]`` with a zero last row, converted only
+at that boundary.
 """
 
 from __future__ import annotations
@@ -46,8 +53,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.collective.executors import LoweredSchedule
 
-from .schedule_runner import (PERMUTE_SCOPE, TABLE_SCOPE, land_receives,
-                              schedule_tables)
+from .schedule_runner import (PERMUTE_SCOPE, TABLE_SCOPE, Window,
+                              land_receives, piece_windows, schedule_tables,
+                              send_rows, slab_shape)
 
 __all__ = [
     "OverlapSlot",
@@ -132,8 +140,9 @@ def build_overlap_plan(schedule: LoweredSchedule,
     return OverlapPlan(schedule, n_compute, slots)
 
 
-def _check_inputs(schedule: LoweredSchedule, shape: Tuple[int, ...]) -> None:
-    """Validate ``[n, D]`` rank-major inputs against the declared init."""
+def _chunk_len(schedule: LoweredSchedule, shape: Tuple[int, ...]) -> int:
+    """Validate ``[n, D]`` rank-major inputs against the declared init;
+    returns the chunk length they make."""
     n, n_chunks = schedule.n, schedule.n_chunks
     if len(shape) != 2 or shape[0] != n:
         raise ValueError(f"want [n={n}, D] rank-major inputs, got {shape}")
@@ -141,37 +150,38 @@ def _check_inputs(schedule: LoweredSchedule, shape: Tuple[int, ...]) -> None:
         if shape[1] % n_chunks:
             raise ValueError(f"D={shape[1]} not divisible by "
                              f"n_chunks={n_chunks}")
-    elif schedule.init == "addressed":
+        return shape[1] // n_chunks
+    if schedule.init == "addressed":
         if n_chunks != n * n or shape[1] % n:
             raise ValueError(f"addressed init wants n_chunks=n^2 and "
                              f"D divisible by n, got {shape}")
-    elif schedule.init != "sharded":
+        return shape[1] // n
+    if schedule.init != "sharded":
         raise ValueError(f"unknown init {schedule.init!r}")
+    return shape[1]
 
 
-def _rank_buffer(schedule: LoweredSchedule, xr, r) -> jnp.ndarray:
-    """Rank ``r``'s ``[n_chunks + 1, chunk_len]`` buffer from its input row.
+def _rank_buffer(schedule: LoweredSchedule, xr, r,
+                 row: Tuple[int, ...]) -> jnp.ndarray:
+    """Rank ``r``'s ``[n_chunks, *row]`` chunk rows from its input row.
 
     ``xr`` is rank ``r``'s row of the declared init (``replicated``: the
     full local vector; ``sharded``: its own chunk; ``addressed``: its n
-    outgoing pieces); the last row is the zero scratch row.
+    outgoing pieces); ``row`` is the shape of one chunk row, the
+    runner's slab or ``(chunk_len,)``.
     """
     n, n_chunks = schedule.n, schedule.n_chunks
     if schedule.init == "replicated":
-        chunk_len = xr.shape[0] // n_chunks
         # the barrier keeps the flat row as it is: fused with the reshape
         # of a [vocab, d_model] gradient that produced it, the split into
         # chunk rows becomes a relayout that takes the TPU compiler minutes
-        body = jax.lax.optimization_barrier(xr).reshape(n_chunks, chunk_len)
-    elif schedule.init == "sharded":
-        chunk_len = xr.shape[0]
-        body = jnp.zeros((n_chunks, chunk_len), xr.dtype).at[r].set(xr)
-    else:                                                    # addressed
-        chunk_len = xr.shape[0] // n
-        body = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_chunks, chunk_len), xr.dtype),
-            xr.reshape(n, chunk_len), (r * n, 0))
-    return jnp.concatenate([body, jnp.zeros((1, chunk_len), xr.dtype)])
+        return jax.lax.optimization_barrier(xr).reshape(n_chunks, *row)
+    zeros = jnp.zeros((n_chunks,) + row, xr.dtype)
+    if schedule.init == "sharded":
+        return jax.lax.dynamic_update_index_in_dim(
+            zeros, xr.reshape(row), r, 0)
+    return jax.lax.dynamic_update_slice_in_dim(              # addressed
+        zeros, xr.reshape((n,) + row), r * n, 0)
 
 
 def seed_state(schedule: LoweredSchedule, x) -> jnp.ndarray:
@@ -179,37 +189,57 @@ def seed_state(schedule: LoweredSchedule, x) -> jnp.ndarray:
 
     The traceable (jnp) counterpart of the runner's initial-buffer
     construction: ``x`` is rank-major per the schedule's declared init,
-    and row ``n_chunks`` is the zero scratch row that absorbs
-    non-participating positions.
+    and row ``n_chunks`` is a zero row the runner never reads.
     """
     x = jnp.asarray(x)
-    _check_inputs(schedule, x.shape)
-    bufs = jax.vmap(lambda xr, r: _rank_buffer(schedule, xr, r))(
+    chunk_len = _chunk_len(schedule, x.shape)
+    bufs = jax.vmap(
+        lambda xr, r: _rank_buffer(schedule, xr, r, (chunk_len,)))(
         x, jnp.arange(schedule.n))
-    return bufs[np.asarray(schedule.rank_of, dtype=np.int64)]
+    return _to_public(bufs[np.asarray(schedule.rank_of, dtype=np.int64)])
 
 
 def finish_state(schedule: LoweredSchedule, state) -> jnp.ndarray:
-    """Back to rank space, scratch row dropped (run_schedule's output)."""
+    """Back to rank space, last row dropped (run_schedule's output)."""
     order = np.asarray(schedule.order, dtype=np.int64)
     return jnp.asarray(state)[order][:, :schedule.n_chunks]
 
 
+def _to_public(rows) -> jnp.ndarray:
+    """``[n, n_chunks, *slab]`` chunk rows as the public state."""
+    n, n_chunks = rows.shape[:2]
+    flat = rows.reshape(n, n_chunks, -1)
+    return jnp.concatenate([flat, jnp.zeros_like(flat[:, :1])], axis=1)
+
+
+def _from_public(schedule: LoweredSchedule, state) -> jnp.ndarray:
+    """The public state as the runner's ``[n, n_chunks, *slab]`` rows."""
+    n, n_chunks = schedule.n, schedule.n_chunks
+    state = jnp.asarray(state)
+    if state.ndim != 3 or state.shape[:2] != (n, n_chunks + 1):
+        raise ValueError(f"want a [n={n}, n_chunks + 1={n_chunks + 1}, "
+                         f"chunk_len] state, got {state.shape}")
+    slab = slab_shape(state.shape[2], schedule.chunk_factor)
+    return state[:, :n_chunks].reshape(n, n_chunks, *slab)
+
+
 def _seed_on_mesh(mesh: Mesh, axis: str, schedule: LoweredSchedule,
                   x) -> jnp.ndarray:
-    """:func:`seed_state` for ``x`` sharded over ``mesh[axis]``.
+    """The runner's chunk rows for ``x`` sharded over ``mesh[axis]``.
 
-    Each device builds its own rank's buffer, and one ``ppermute`` moves
-    rank ``rank_of[p]``'s buffer to position p: the state never leaves
+    Each device builds its own rank's rows, and one ``ppermute`` moves
+    rank ``rank_of[p]``'s rows to position p: the state never leaves
     its shards (a global gather over the sharded axis is what the TPU
     compiler handles slowly at gradient sizes).
     """
-    _check_inputs(schedule, tuple(np.shape(x)))
+    chunk_len = _chunk_len(schedule, tuple(np.shape(x)))
+    slab = slab_shape(chunk_len, schedule.chunk_factor)
     links = [(int(r), p) for p, r in enumerate(schedule.rank_of)]
 
     def per_device(rows):
         with jax.named_scope(TABLE_SCOPE):
-            buf = _rank_buffer(schedule, rows[0], jax.lax.axis_index(axis))
+            buf = _rank_buffer(schedule, rows[0], jax.lax.axis_index(axis),
+                               slab)
         with jax.named_scope(PERMUTE_SCOPE):
             return jax.lax.ppermute(buf, axis, links)[None]
 
@@ -219,24 +249,26 @@ def _seed_on_mesh(mesh: Mesh, axis: str, schedule: LoweredSchedule,
 
 def _finish_on_mesh(mesh: Mesh, axis: str, schedule: LoweredSchedule,
                     state) -> jnp.ndarray:
-    """:func:`finish_state` for a state sharded over ``mesh[axis]``."""
+    """Rank-space ``[n, n_chunks, chunk_len]`` from the runner's rows
+    sharded over ``mesh[axis]``."""
     links = [(p, int(r)) for p, r in enumerate(schedule.rank_of)]
 
     def per_device(rows):
         with jax.named_scope(PERMUTE_SCOPE):
             moved = jax.lax.ppermute(rows[0], axis, links)
         with jax.named_scope(TABLE_SCOPE):
-            return moved[None, :schedule.n_chunks]
+            return moved.reshape(1, schedule.n_chunks, -1)
 
     return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
                          out_specs=P(axis), check_vma=False)(state)
 
 
-def _make_issue(mesh: Mesh, axis: str, rnd_tables, cols: slice):
-    """shard_map'd transfer of one (round, piece): gather + ppermute.
+def _make_issue(mesh: Mesh, axis: str, rnd_tables, window: Window,
+                n_chunks: int):
+    """shard_map'd transfer of one (round, piece): row reads + ppermute.
 
     Returns ``None`` when the round has no effective links.  Output is
-    one staged ``[n, m, piece_len]`` array per effective step — a value
+    one staged ``[n, m, *piece]`` array per effective step — a value
     with no dependency on anything but round-entry state, so resident
     compute traced between issue and apply is free to overlap it.
     """
@@ -250,8 +282,7 @@ def _make_issue(mesh: Mesh, axis: str, rnd_tables, cols: slice):
         outs = []
         for eff_links, send in live:
             with jax.named_scope(TABLE_SCOPE):
-                my_send = jnp.asarray(send)[me]           # [m]
-                payload = buf[my_send, cols]
+                payload = send_rows(buf, me, send, window, n_chunks)
             with jax.named_scope(PERMUTE_SCOPE):
                 outs.append(jax.lax.ppermute(payload, axis, eff_links)[None])
         return tuple(outs)
@@ -262,8 +293,8 @@ def _make_issue(mesh: Mesh, axis: str, rnd_tables, cols: slice):
 
 
 def _make_apply(mesh: Mesh, axis: str, rnd_tables, rnd_ops,
-                cols: slice, n_chunks: int, use_pallas_add: bool):
-    """shard_map'd round barrier: land staged receives, re-zero scratch."""
+                window: Window, n_chunks: int, use_pallas_add: bool):
+    """shard_map'd round barrier: land the staged receives."""
     live = [((eff, recv), op)
             for (eff, _, recv), op in zip(rnd_tables, rnd_ops) if eff]
     if not live:
@@ -273,7 +304,7 @@ def _make_apply(mesh: Mesh, axis: str, rnd_tables, rnd_ops,
         buf = rows[0]
         me = jax.lax.axis_index(axis)
         for ((eff_links, recv), op), rx in zip(live, staged):
-            buf = land_receives(buf, me, recv, cols, op, rx[0], n_chunks,
+            buf = land_receives(buf, me, recv, window, op, rx[0], n_chunks,
                                 use_pallas_add)
         return buf[None]
 
@@ -300,7 +331,8 @@ def run_overlapped(
     :class:`LoweredSchedule` (a default plan is built over it).  With a
     bare schedule, ``rounds=(start, stop)`` executes only that window
     (via :meth:`LoweredSchedule.slice_rounds`); pass ``state`` to resume
-    mid-stream and ``return_state=True`` to keep pipelining later.
+    mid-stream and ``return_state=True`` to keep pipelining later; that
+    state is :func:`seed_state`'s ``[n, n_chunks + 1, chunk_len]``.
 
     Returns ``(out, results)``: ``out`` matches
     :func:`~repro.kernels.schedule_runner.run_schedule` element for
@@ -326,26 +358,21 @@ def run_overlapped(
                          f"devices, schedule wants {n}")
     if state is None:
         state = _seed_on_mesh(mesh, axis, schedule, x)
-    state = jnp.asarray(state)
-    chunk_len = state.shape[-1]
+    else:
+        state = _from_public(schedule, state)
     k = max(1, schedule.chunk_factor)
-    if chunk_len % k:
-        raise ValueError(
-            f"chunk_len {chunk_len} not divisible by chunk_factor {k}")
-    piece_len = chunk_len // k
+    windows = piece_windows(state.shape[2] * state.shape[3], k)
 
     tables, ops = schedule_tables(schedule)
-    # a piece is a static column window of every chunk row
-    piece_cols = [slice(p * piece_len, (p + 1) * piece_len)
-                  for p in range(k)]
 
     def stage_fns(slot):
         if slot.round_index < 0:
             return None, None
-        cols = piece_cols[slot.piece]
-        issue = _make_issue(mesh, axis, tables[slot.round_index], cols)
+        window = windows[slot.piece]
+        issue = _make_issue(mesh, axis, tables[slot.round_index], window,
+                            n_chunks)
         apply_ = _make_apply(mesh, axis, tables[slot.round_index],
-                             ops[slot.round_index], cols, n_chunks,
+                             ops[slot.round_index], window, n_chunks,
                              use_pallas_add)
         return issue, apply_
 
@@ -362,7 +389,7 @@ def run_overlapped(
             issue_next = fns[i + 1][0]
             same_round = slots[i + 1].round_index == slot.round_index
         # double buffer: the next piece of this round reads the same
-        # round-entry columns, so its transfer goes on the wire before
+        # round-entry rows, so its transfer goes on the wire before
         # this slot's reduce lands
         if issue_next is not None and same_round:
             staged_next = issue_next(state)
@@ -376,5 +403,5 @@ def run_overlapped(
             staged_next = issue_next(state)
 
     if return_state:
-        return state, results
+        return _to_public(state), results
     return _finish_on_mesh(mesh, axis, schedule, state), results

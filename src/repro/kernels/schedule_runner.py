@@ -5,15 +5,16 @@ instead of a hand-derived ring, this runner interprets the per-round
 ``PermuteStep``\\ s the :class:`~repro.collective.JaxExecutor` lowering
 produced — so *any* registered algorithm (trees, halving-doubling,
 bcube, recursive-doubling...) runs on real devices through
-``jax.lax.ppermute``, with the reduce accumulation fused by the same
-Pallas :func:`~repro.kernels.ring_collective.fused_add` kernel.
+``jax.lax.ppermute``.  The reduce is XLA's add, which fuses into the
+update that lands it, or with ``use_pallas_add`` the Pallas
+:func:`~repro.kernels.ring_collective.fused_add` kernel.
 
 Execution semantics mirror the translation validator exactly
 (:mod:`repro.analysis.equiv`):
 
 * the mesh-axis index IS the schedule's position space; device p holds
   logical rank ``schedule.rank_of[p]``'s buffer;
-* rounds are barriers: every step's payload is gathered from the
+* rounds are barriers: every step's payload is read from the
   round-entry buffer, all receives are staged, and applied together at
   the round boundary;
 * a link ``(s, d)`` fires iff ``send_mask[s] and recv_mask[d]``;
@@ -22,11 +23,17 @@ Execution semantics mirror the translation validator exactly
 * ``chunk_factor`` k pipelines the body serially over k payload
   slices.
 
-The local buffer is ``[n_chunks + 1, chunk_len]`` per device — row
-``n_chunks`` is a zero scratch row that absorbs the gather/scatter of
-non-participating positions, keeping every step a static dense
-``ppermute`` (no per-device control flow, so the whole schedule jits
-to one XLA program).
+The local buffer is ``[n_chunks, *slab]`` per device: the chunk index
+is the major axis and each chunk row is a tile-dense slab
+(:func:`slab_shape`), ``[chunk_len // 128, 128]`` when each of the k
+pieces is a whole number of 128-lane rows, else ``[1, chunk_len]``.
+Each step's ``m`` chunk slots are read and written one row at a time
+with dynamic slices indexed by the static SEND/RECV tables, so a round
+moves the rows it sends and lands, not the whole buffer.  A position
+with nothing to send in a slot sends row 0, which no one lands; one
+with nothing to receive keeps its row through a select on the landed
+row.  Every step stays a static dense ``ppermute`` (no per-device
+control flow, so the whole schedule jits to one XLA program).
 
 This module never certifies anything itself: callers obtain schedules
 through ``Session.lower`` / ``JaxExecutor.lower`` where
@@ -38,7 +45,7 @@ runner can be this trusting *because* the schedule carries a proof.
 from __future__ import annotations
 
 import functools
-from typing import Callable, List, Tuple
+from typing import Callable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,24 +57,57 @@ from repro.collective.executors import LoweredSchedule
 from .ring_collective import accumulate
 
 __all__ = ["run_schedule", "schedule_body", "check_postcondition",
-           "schedule_tables", "land_receives", "PERMUTE_SCOPE",
-           "TABLE_SCOPE", "ADD_SCOPE"]
+           "schedule_tables", "land_receives", "send_rows", "slab_shape",
+           "piece_windows", "PERMUTE_SCOPE", "TABLE_SCOPE", "ADD_SCOPE"]
 
 #: device scopes of the certified paths (here and in
-#: :mod:`repro.kernels.overlap`): every ``ppermute``; the gather and
-#: scatter through the SEND/RECV tables and the scratch row's upkeep;
-#: the reduce
+#: :mod:`repro.kernels.overlap`): every ``ppermute``; the row reads and
+#: writes through the SEND/RECV tables, seeding and finishing; the reduce
 PERMUTE_SCOPE = "certified.permute"
 TABLE_SCOPE = "certified.table"
 ADD_SCOPE = "certified.add"
 
+#: lanes of a TPU vreg: a slab row of this width is tile-dense
+LANES = 128
+
+#: a piece's static place in a chunk row's slab: ``(starts, sizes)``
+Window = Tuple[Tuple[int, int], Tuple[int, int]]
+
+
+def slab_shape(chunk_len: int, chunk_factor: int = 1) -> Tuple[int, int]:
+    """Shape of one chunk row in the runner's buffer.
+
+    ``[chunk_len // 128, 128]`` when each of the ``chunk_factor`` pieces
+    is a whole number of lane rows, so a row is whole tiles on every
+    backend's layout; else ``[1, chunk_len]``.
+    """
+    k = max(1, chunk_factor)
+    if chunk_len % k:
+        raise ValueError(
+            f"chunk_len {chunk_len} not divisible by chunk_factor {k}")
+    if (chunk_len // k) % LANES == 0:
+        return chunk_len // LANES, LANES
+    return 1, chunk_len
+
+
+def piece_windows(chunk_len: int, chunk_factor: int = 1) -> List[Window]:
+    """Each piece's static window in a chunk row's :func:`slab_shape`:
+    lane rows of a dense slab, else columns of the one row."""
+    k = max(1, chunk_factor)
+    tiles, lanes = slab_shape(chunk_len, k)
+    if lanes == LANES:
+        t = tiles // k
+        return [((p * t, 0), (t, LANES)) for p in range(k)]
+    w = chunk_len // k
+    return [((0, p * w), (1, w)) for p in range(k)]
+
 
 def _step_tables(step, n: int, n_chunks: int):
-    """Static gather/scatter tables of one PermuteStep.
+    """Static send/land tables of one PermuteStep.
 
     Returns ``(eff_links, SEND, RECV)``: the mask-filtered ppermute
-    link list and ``[n, m]`` int32 chunk-row tables (pad entries point
-    at the zero scratch row ``n_chunks``).
+    link list and ``[n, m]`` int32 chunk-row tables (``n_chunks`` marks
+    a slot the position does not send or receive).
     """
     m = max((len(c) for c in step.chunks), default=0)
     m = max(m, 1)
@@ -94,7 +134,7 @@ def schedule_tables(schedule: LoweredSchedule):
     ``(tables, ops)`` where ``tables[r][s]`` is :func:`_step_tables` of
     round ``r``'s step ``s`` and ``ops[r][s]`` its reduce/copy tag.
     The cached arrays are read-only by convention — every consumer
-    gathers from them without mutation.
+    reads them without mutation.
     """
     tables = tuple(
         tuple(_step_tables(step, schedule.n, schedule.n_chunks)
@@ -104,9 +144,8 @@ def schedule_tables(schedule: LoweredSchedule):
     return tables, ops
 
 
-def _initial_buffers(schedule: LoweredSchedule,
-                     x: np.ndarray) -> Tuple[np.ndarray, int]:
-    """Rank-space ``[n, n_chunks + 1, chunk_len]`` buffers from inputs.
+def _initial_buffers(schedule: LoweredSchedule, x) -> np.ndarray:
+    """Rank-space ``[n, n_chunks, chunk_len]`` buffers from inputs.
 
     ``x`` is rank-major: row r is logical rank r's contribution, shaped
     by the schedule's declared init (``replicated``: the full local
@@ -118,42 +157,68 @@ def _initial_buffers(schedule: LoweredSchedule,
     assert x.ndim == 2 and x.shape[0] == n, x.shape
     if schedule.init == "replicated":
         assert x.shape[1] % n_chunks == 0, (x.shape, n_chunks)
-        chunk_len = x.shape[1] // n_chunks
-        buf = np.zeros((n, n_chunks + 1, chunk_len), dtype=x.dtype)
-        buf[:, :n_chunks] = x.reshape(n, n_chunks, chunk_len)
-    elif schedule.init == "sharded":
-        chunk_len = x.shape[1]
-        buf = np.zeros((n, n_chunks + 1, chunk_len), dtype=x.dtype)
+        return x.reshape(n, n_chunks, x.shape[1] // n_chunks)
+    if schedule.init == "sharded":
+        buf = np.zeros((n, n_chunks, x.shape[1]), dtype=x.dtype)
         for r in range(n):
             buf[r, r] = x[r]
-    elif schedule.init == "addressed":
+        return buf
+    if schedule.init == "addressed":
         assert n_chunks == n * n and x.shape[1] % n == 0, (x.shape, n_chunks)
-        chunk_len = x.shape[1] // n
-        buf = np.zeros((n, n_chunks + 1, chunk_len), dtype=x.dtype)
+        buf = np.zeros((n, n_chunks, x.shape[1] // n), dtype=x.dtype)
         for s in range(n):
-            buf[s, s * n:(s + 1) * n] = x[s].reshape(n, chunk_len)
-    else:
-        raise ValueError(f"unknown init {schedule.init!r}")
-    return buf, chunk_len
+            buf[s, s * n:(s + 1) * n] = x[s].reshape(n, -1)
+        return buf
+    raise ValueError(f"unknown init {schedule.init!r}")
 
 
-def land_receives(buf, me, recv, cols: slice, op: str, received,
+def _entry(column: Sequence, me) -> jnp.ndarray:
+    """This device's entry of a static per-position table column."""
+    return jax.lax.dynamic_index_in_dim(jnp.asarray(column), me,
+                                        keepdims=False)
+
+
+def send_rows(buf, me, send, window: Window, n_chunks: int) -> jnp.ndarray:
+    """This device's ``[m, *piece]`` payload of one step: the SEND
+    table's rows, one dynamic slice each.  An unused slot reads row 0,
+    which its destination does not land."""
+    start, size = window
+    rows = []
+    for j in range(send.shape[1]):
+        col = np.where(send[:, j] < n_chunks, send[:, j], 0)
+        rows.append(jax.lax.dynamic_slice(
+            buf, (_entry(col.astype(np.int32), me),) + start, (1,) + size))
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows)
+
+
+def land_receives(buf, me, recv, window: Window, op: str, received,
                   n_chunks: int, use_pallas_add: bool) -> jnp.ndarray:
     """Land one step's receives in this device's ``buf``: ``reduce``
     accumulates into the RECV table's rows, ``copy`` overwrites them.
 
-    Non-receiving positions land in the scratch row, which is re-zeroed
-    so every later gather still reads zeros."""
-    with jax.named_scope(TABLE_SCOPE):
-        my_recv = jnp.asarray(recv)[me]                      # [m]
-    if op == "reduce":
+    Each slot is one row read and written in place.  Where some
+    position receives nothing in a slot, a select keeps that
+    position's row as it was."""
+    start, size = window
+    for j in range(recv.shape[1]):
+        gets = recv[:, j] < n_chunks
+        if not gets.any():
+            continue
+        keep = not gets.all()
         with jax.named_scope(TABLE_SCOPE):
-            current = buf[my_recv, cols]
-        with jax.named_scope(ADD_SCOPE):
-            received = accumulate(current, received, use_pallas_add)
-    with jax.named_scope(TABLE_SCOPE):
-        buf = buf.at[my_recv, cols].set(received)
-        return buf.at[n_chunks].set(jnp.zeros_like(buf[n_chunks]))
+            row = _entry(np.where(gets, recv[:, j], 0).astype(np.int32), me)
+            at = (row,) + start
+            if op == "reduce" or keep:
+                current = jax.lax.dynamic_slice(buf, at, (1,) + size)
+        new = received[j:j + 1]
+        if op == "reduce":
+            with jax.named_scope(ADD_SCOPE):
+                new = accumulate(current, new, use_pallas_add)
+        with jax.named_scope(TABLE_SCOPE):
+            if keep:
+                new = jnp.where(_entry(gets, me), new, current)
+            buf = jax.lax.dynamic_update_slice(buf, new, at)
+    return buf
 
 
 def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
@@ -161,11 +226,11 @@ def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
                   ) -> Callable[[jnp.ndarray], jnp.ndarray]:
     """The traceable body of :func:`run_schedule`, over ``mesh[axis]``.
 
-    Maps position-major ``[n, n_chunks + 1, chunk_len]`` buffers (the
-    device at axis position p holds logical rank ``rank_of[p]``'s
-    buffer, row ``n_chunks`` the zero scratch row) to the same layout
-    after the last round.  It depends only on the buffers' shape, so it
-    can be lowered from a ``ShapeDtypeStruct``.
+    Maps position-major ``[n, n_chunks, *slab_shape(chunk_len,
+    chunk_factor)]`` buffers (the device at axis position p holds
+    logical rank ``rank_of[p]``'s chunk rows) to the same layout after
+    the last round.  It depends only on the buffers' shape, so it can
+    be lowered from a ``ShapeDtypeStruct``.
     """
     n = schedule.n
     if mesh.shape[axis] != n:
@@ -177,16 +242,14 @@ def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
     tables, ops = schedule_tables(schedule)
 
     def per_device(rows):
-        buf = rows[0]                              # [n_chunks+1, chunk_len]
-        chunk_len = buf.shape[-1]
-        if chunk_len % k:
+        buf = rows[0]                              # [n_chunks, *slab]
+        chunk_len = buf.shape[1] * buf.shape[2]
+        if buf.shape[1:] != slab_shape(chunk_len, k):
             raise ValueError(
-                f"chunk_len {chunk_len} not divisible by chunk_factor {k}")
-        piece_len = chunk_len // k
+                f"chunk rows {buf.shape[1:]} are not the slab "
+                f"{slab_shape(chunk_len, k)} of chunk_len {chunk_len}")
         me = jax.lax.axis_index(axis)
-        for piece in range(k):
-            # a piece is a static column window of every chunk row
-            cols = slice(piece * piece_len, (piece + 1) * piece_len)
+        for window in piece_windows(chunk_len, k):
             for rnd_tables, rnd_ops in zip(tables, ops):
                 entry = buf                        # round-entry snapshot
                 staged = []
@@ -195,8 +258,8 @@ def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
                         staged.append(None)
                         continue
                     with jax.named_scope(TABLE_SCOPE):
-                        my_send = jnp.asarray(send)[me]      # [m]
-                        payload = entry[my_send, cols]
+                        payload = send_rows(entry, me, send, window,
+                                            schedule.n_chunks)
                     with jax.named_scope(PERMUTE_SCOPE):
                         staged.append(
                             jax.lax.ppermute(payload, axis, eff_links))
@@ -204,12 +267,20 @@ def schedule_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
                         rnd_tables, rnd_ops, staged):
                     if received is None:
                         continue
-                    buf = land_receives(buf, me, recv, cols, op, received,
+                    buf = land_receives(buf, me, recv, window, op, received,
                                         schedule.n_chunks, use_pallas_add)
         return buf[None]
 
     return jax.shard_map(per_device, mesh=mesh, in_specs=(P(axis),),
                          out_specs=P(axis), check_vma=False)
+
+
+@functools.lru_cache(maxsize=64)
+def _jitted_body(mesh: Mesh, axis: str, schedule: LoweredSchedule,
+                 use_pallas_add: bool):
+    """:func:`schedule_body` compiled once per schedule: run eagerly, a
+    shard_map dispatches every row read and write on its own."""
+    return jax.jit(schedule_body(mesh, axis, schedule, use_pallas_add))
 
 
 def run_schedule(
@@ -227,14 +298,16 @@ def run_schedule(
     declared postcondition can be checked
     (:func:`check_postcondition`).
     """
-    body = schedule_body(mesh, axis, schedule, use_pallas_add)
-    buf0, _ = _initial_buffers(schedule, x)
+    body = _jitted_body(mesh, axis, schedule, use_pallas_add)
+    buf0 = _initial_buffers(schedule, x)
+    n, n_chunks, chunk_len = buf0.shape
+    slab = slab_shape(chunk_len, schedule.chunk_factor)
     # device at axis position p plays logical rank rank_of[p]
     rank_of = np.asarray(schedule.rank_of, dtype=np.int64)
-    out_pos = body(jnp.asarray(buf0[rank_of]))     # position-major
-    # back to rank space, scratch row dropped
+    out_pos = body(jnp.asarray(buf0[rank_of].reshape(n, n_chunks, *slab)))
+    # back to rank space
     order = np.asarray(schedule.order, dtype=np.int64)
-    return jnp.asarray(out_pos)[order][:, :schedule.n_chunks]
+    return jnp.asarray(out_pos)[order].reshape(n, n_chunks, chunk_len)
 
 
 def check_postcondition(schedule: LoweredSchedule, x,

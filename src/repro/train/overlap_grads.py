@@ -42,6 +42,7 @@ from repro.collective import CollectiveOp, JaxExecutor, compile_op
 from repro.collective.executors import LoweredSchedule
 from repro.collective.passes import apply_permutation, chunk as chunk_pass
 from repro.kernels.overlap import run_overlapped
+from repro.kernels.schedule_runner import LANES
 from repro.optim import apply_opt
 
 from .train_step import TrainState
@@ -145,6 +146,11 @@ class OverlapGradReducer:
     results.  The same certified schedule runs every bucket — the
     lowering is payload-agnostic, so the runner's memoised SEND/RECV
     tables hit across buckets and steps.
+
+    Each bucket vector is padded so that every piece of a chunk is a
+    whole number of 128-lane rows: the runner then holds each chunk as
+    a tile-dense slab on a major axis, and the finish slices the
+    padding off.
     """
 
     def __init__(self, mesh: Mesh, axis: str, schedule: LoweredSchedule,
@@ -181,7 +187,8 @@ class OverlapGradReducer:
         # set while tracing: the number of buckets the compiled step runs
         obs.metrics().gauge("train.overlap.buckets").set(len(buckets))
         n = self.n
-        quantum = self.schedule.n_chunks * max(1, self.schedule.chunk_factor)
+        quantum = (self.schedule.n_chunks * max(1, self.schedule.chunk_factor)
+                   * LANES)
 
         payloads = []
         with jax.named_scope(PACK_SCOPE):

@@ -11,6 +11,7 @@ per-bucket postconditions, ``Session.overlap_step``, and the serve
 engine's armed decode/prefill overlap.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -19,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro.analysis.lint import lint_file
+from repro.collective import registered_builders
 from repro.kernels import schedule_runner
 from repro.plan.compiler import PlanEntry
 from repro.session.config import OverlapConfig, SessionConfig
@@ -197,60 +199,168 @@ def test_lint_direct_schedule_run(tmp_path):
 # 8-device host mesh: overlap runner == run_schedule, bitwise
 # ---------------------------------------------------------------------------
 
-def test_overlapped_matches_run_schedule_8dev():
+@pytest.mark.parametrize("algo", sorted(registered_builders()))
+def test_overlapped_matches_run_schedule_8dev(algo):
+    """Every algorithm, chunk_factor 1 and 2, a lane-aligned chunk (dense
+    slab rows) and one that is not (``[1, chunk_len]`` rows): the
+    overlap runner equals run_schedule bitwise alone, with compute
+    shards, and resumed across the public state between two windows."""
     prog = """
 import numpy as np
 import jax
 from jax.sharding import Mesh
-from repro.collective import CollectiveOp, compile_op, JaxExecutor
+from repro.collective import CollectiveOp, compile_op, JaxExecutor, get_builder
+from repro.collective.builders import candidates
 from repro.collective.passes import apply_permutation, chunk
 from repro.analysis import require_certified
 from repro.kernels.schedule_runner import (
-    run_schedule, check_postcondition, schedule_tables)
+    run_schedule, check_postcondition, schedule_tables, slab_shape)
 from repro.kernels.overlap import (
     build_overlap_plan, run_overlapped, seed_state, finish_state)
 
+algo = ALGO
 n = 8
 mesh = Mesh(np.array(jax.devices()[:n]), ("x",))
 ex = JaxExecutor()
 perm = [3, 1, 4, 7, 5, 0, 2, 6]
-for algo, k in [("ring", 2), ("halving_doubling", 1)]:
-    op = CollectiveOp(kind="allreduce", size_bytes=1 << 12,
-                      group=tuple(range(n)))
-    prog = apply_permutation(compile_op(op, algo), perm)
+kind = get_builder(algo).kinds[0]
+akw = next(a for b, a in candidates(kind, n) if b == algo)
+# ring_sequential's second lap re-reduces the circulating partial sums:
+# its postcondition holds over contributor sets, not over the numbers
+numeric = algo != "ring_sequential"
+for k in (1, 2):
+    op = CollectiveOp(kind=kind, size_bytes=1 << 12, group=tuple(range(n)))
+    prog = apply_permutation(compile_op(op, algo, **akw), perm)
     if k > 1:
         prog = chunk(prog, k)
     sched = ex.lower_schedule(prog)
     require_certified(prog, sched)
-    d = (1 << 12) // 4
-    x = np.arange(n * d, dtype=np.float32).reshape(n, d) / (n * d)
-    ref = np.asarray(run_schedule(x, mesh, "x", sched, use_pallas_add=False))
-    # no-compute overlap: bitwise identical to the plain runner
-    out, _ = run_overlapped(x, mesh, "x", sched, use_pallas_add=False)
-    assert np.array_equal(ref, np.asarray(out)), (algo, k)
-    assert not check_postcondition(sched, x, np.asarray(out))
-    # with compute shards interleaved: same result, shards all ran
-    comp = [lambda i=i: jax.numpy.sum(jax.numpy.ones((16, 16)) * i)
-            for i in range(5)]
-    plan = build_overlap_plan(sched, 5)
-    out2, res = run_overlapped(x, mesh, "x", plan, compute=comp,
-                               use_pallas_add=False)
-    assert np.array_equal(ref, np.asarray(out2)), (algo, k)
-    assert [float(r) for r in res] == [256.0 * i for i in range(5)]
-    # sliced composition: window [0, m) then [m, end) == one shot
-    m = max(1, len(sched.rounds) // 2)
-    st = seed_state(sched, x)
-    st, _ = run_overlapped(None, mesh, "x", sched, state=st, rounds=(0, m),
-                           return_state=True, use_pallas_add=False)
-    st, _ = run_overlapped(None, mesh, "x", sched, state=st,
-                           rounds=(m, None), return_state=True,
-                           use_pallas_add=False)
-    assert np.array_equal(ref, np.asarray(finish_state(sched, st))), (algo, k)
+    for chunk_len, dense in ((256 * k, True), (6 * k, False)):
+        assert (slab_shape(chunk_len, k)[1] == 128) == dense
+        d = chunk_len * {"replicated": sched.n_chunks, "sharded": 1,
+                         "addressed": n}[sched.init]
+        x = np.arange(n * d, dtype=np.float32).reshape(n, d) / (n * d)
+        ref = np.asarray(run_schedule(x, mesh, "x", sched,
+                                      use_pallas_add=False))
+        assert ref.shape == (n, sched.n_chunks, chunk_len)
+        if numeric:
+            assert not check_postcondition(sched, x, ref), (k, chunk_len)
+        # no-compute overlap: bitwise identical to the plain runner
+        out = jax.jit(lambda x: run_overlapped(
+            x, mesh, "x", sched, use_pallas_add=False)[0])(x)
+        assert np.array_equal(ref, np.asarray(out)), (k, chunk_len)
+        # with compute shards interleaved: same result, shards all ran
+        comp = [lambda i=i: jax.numpy.sum(jax.numpy.ones((16, 16)) * i)
+                for i in range(5)]
+        plan = build_overlap_plan(sched, 5)
+        out2, res = jax.jit(lambda x: run_overlapped(
+            x, mesh, "x", plan, compute=comp, use_pallas_add=False))(x)
+        assert np.array_equal(ref, np.asarray(out2)), (k, chunk_len)
+        assert [float(r) for r in res] == [256.0 * i for i in range(5)]
+        # sliced composition through the public [n, n_chunks + 1,
+        # chunk_len] state: window [0, m) then [m, end) == one shot
+        m = max(1, len(sched.rounds) // 2)
+        st = seed_state(sched, x)
+        for window in ((0, m), (m, None)):
+            assert st.shape == (n, sched.n_chunks + 1, chunk_len)
+            st = jax.jit(lambda st: run_overlapped(
+                None, mesh, "x", sched, state=st, rounds=window,
+                return_state=True, use_pallas_add=False)[0])(st)
+        assert np.array_equal(ref, np.asarray(finish_state(sched, st))), \
+            (k, chunk_len)
     print(algo, k, "OK")
 assert schedule_tables.cache_info().hits > 0
 print("OVERLAP RUNNER OK")
-"""
+""".replace("ALGO", repr(algo))
     _run_sub(prog, "OVERLAP RUNNER OK")
+
+
+# ---------------------------------------------------------------------------
+# the table path's lowering: row reads and writes on a major chunk axis
+# ---------------------------------------------------------------------------
+
+_TABLE_TARGETS = ("reducer", "ring", "halving_doubling")
+
+
+@pytest.fixture(scope="module")
+def table_lowerings(tmp_path_factory):
+    """HLO facts of the certified table path, lowered on a 4-device mesh:
+    the bucketed reducer (ring, buckets padded by its lane quantum) and
+    ``schedule_body`` for a ring and for halving-doubling (steps of
+    several chunks).  Per target: whether any gather or scatter is left,
+    the chunk count, and the result shapes of the dynamic slices and
+    updates in the ``certified.table`` scope."""
+    out = tmp_path_factory.mktemp("tables") / "tables.json"
+    prog = r"""
+import json, re, sys
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.kernels.schedule_runner import schedule_body, slab_shape
+from repro.train.overlap_grads import OverlapGradReducer, certified_allreduce
+
+mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+rows = NamedSharding(mesh, P("data"))
+sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows)
+facts = {}
+
+def record(name, lowered, n_chunks):
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    shapes = []
+    for line in text.splitlines():
+        op = re.search(r"= f32\[([0-9,]*)\]\S* (dynamic-slice|"
+                       r"dynamic-update-slice)\(", line)
+        if op and "certified.table" in line:
+            shapes.append([int(d) for d in op.group(1).split(",")])
+    facts[name] = {"gather": "gather(" in text,
+                   "scatter": "scatter(" in text,
+                   "n_chunks": n_chunks, "table_shapes": shapes}
+
+sched = certified_allreduce(4, 1 << 14, algo="ring")
+red = OverlapGradReducer(mesh, "data", sched, bucket_bytes=1 << 14)
+tree = {"w": sds((4, 96, 64)), "b": sds((4, 1000))}
+record("reducer", jax.jit(lambda g: red(g)[0]).lower(tree), sched.n_chunks)
+for algo in ("ring", "halving_doubling"):
+    sched = certified_allreduce(4, 1 << 14, algo=algo)
+    buf = sds((4, sched.n_chunks) + slab_shape(1024))
+    record(algo, jax.jit(schedule_body(mesh, "data", sched, False))
+           .lower(buf), sched.n_chunks)
+json.dump(facts, open(sys.argv[1], "w"))
+print("TABLES LOWERED")
+"""
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH="src")
+    r = subprocess.run([sys.executable, "-c", prog, str(out)],
+                       capture_output=True, text=True, env=env,
+                       timeout=600, cwd=REPO)
+    assert r.returncode == 0 and "TABLES LOWERED" in r.stdout, \
+        r.stdout + r.stderr
+    return json.loads(out.read_text())
+
+
+@pytest.mark.parametrize("target", _TABLE_TARGETS)
+def test_table_path_has_no_gather_or_scatter(table_lowerings, target):
+    facts = table_lowerings[target]
+    assert not facts["gather"], target
+    assert not facts["scatter"], target
+
+
+@pytest.mark.parametrize("target", _TABLE_TARGETS)
+def test_table_rows_keep_the_chunk_on_a_major_axis(table_lowerings,
+                                                   target):
+    """Every lane-aligned buffer the table scope slices or updates is
+    ``[rows, tiles, 128]``: the chunk (or the one row read) on axis 0,
+    each row whole lane rows."""
+    facts = table_lowerings[target]
+    shapes = facts["table_shapes"]
+    assert shapes, target
+    updates = [s for s in shapes if s[0] == facts["n_chunks"]]
+    assert updates, shapes
+    for shape in shapes:
+        assert len(shape) == 3 and shape[2] == 128, (target, shape)
+        assert shape[0] in (1, facts["n_chunks"]), (target, shape)
 
 
 # ---------------------------------------------------------------------------

@@ -94,7 +94,7 @@ def test_flash_attention_compiles_at_qwen2_widths(one_chip):
 
 def test_certified_allreduce_body_compiles_on_four_chips(topo, monkeypatch):
     from repro.kernels import ring_collective
-    from repro.kernels.schedule_runner import schedule_body
+    from repro.kernels.schedule_runner import schedule_body, slab_shape
     from repro.train.overlap_grads import certified_allreduce
 
     # this process's backend is the CPU; on the chip the runner compiles
@@ -103,7 +103,7 @@ def test_certified_allreduce_body_compiles_on_four_chips(topo, monkeypatch):
     mesh = Mesh(np.array(topo.devices), ("data",))
     sched = certified_allreduce(4, 1 << 20, perm=[2, 0, 3, 1])
     chunk_len = (1 << 18) // sched.n_chunks          # 1 MiB of f32
-    buf = _sds((4, sched.n_chunks + 1, chunk_len), jnp.float32,
+    buf = _sds((4, sched.n_chunks) + slab_shape(chunk_len), jnp.float32,
                NamedSharding(mesh, P("data")))
     text = jax.jit(schedule_body(mesh, "data", sched)).lower(buf) \
         .compile().as_text()
